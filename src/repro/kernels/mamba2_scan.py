@@ -23,12 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 128
 
@@ -97,7 +92,7 @@ def mamba2_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     af = jnp.tile(a, B)                       # [B*H]
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
-    scratch = [pltpu.VMEM((P, N), jnp.float32)] if _HAVE_PLTPU else None
+    scratch = [pltpu.VMEM((P, N), jnp.float32)]
     y, hT = pl.pallas_call(
         kernel,
         grid=(B * H, nc),

@@ -1,57 +1,46 @@
 """Jit'd public wrappers for the Pallas kernels, with platform dispatch.
 
 On TPU these call the Pallas kernels (``flash_attention.py``,
-``decode_attention.py``, ``rwkv6_scan.py``, ``mamba2_scan.py``); elsewhere
-(CPU dry-runs, tests, this container) they fall back to the pure-jnp
-oracles in ``ref.py`` — identical semantics, validated by the per-kernel
-allclose sweeps in tests/test_kernels.py (which run the Pallas bodies in
-``interpret=True`` mode).
-
-Set ``REPRO_FORCE_REF=1`` to force the reference path, or
-``REPRO_FORCE_PALLAS=interpret`` to force interpret-mode Pallas (testing).
+``decode_attention.py``, ``rwkv6_scan.py``, ``mamba2_scan.py``); on any
+other backend (CPU dry-runs, tests) they take the pure-jnp oracles in
+``ref.py`` — identical semantics, validated by the per-kernel allclose
+sweeps in tests/test_kernels.py (which run the Pallas bodies with
+``interpret=True``) and compiled for a described v5e by
+tests/test_tpu_compile.py. There is no switch to take the oracle on a
+TPU: the backend alone decides.
 """
 
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from . import ref
+from .decode_attention import (check_page_size, flash_decode,
+                               flash_decode_paged)
+from .flash_attention import flash_attention
+from .mamba2_scan import mamba2_chunked
+from .rwkv6_scan import rwkv6_chunked
 
 __all__ = ["attention", "decode_attention", "paged_decode_attention",
-           "rwkv6_scan", "mamba2_scan", "pallas_mode"]
+           "check_page_size", "rwkv6_scan", "mamba2_scan", "pallas_mode"]
 
 
 @functools.lru_cache(None)
 def pallas_mode() -> str:
-    """'tpu' | 'interpret' | 'off'."""
-    if os.environ.get("REPRO_FORCE_REF"):
-        return "off"
-    forced = os.environ.get("REPRO_FORCE_PALLAS", "")
-    if forced == "interpret":
-        return "interpret"
-    try:
-        if jax.default_backend() == "tpu":
-            return "tpu"
-    except Exception:
-        pass
-    return "off"
+    """'tpu' when the default backend is a TPU, else 'off'."""
+    return "tpu" if jax.default_backend() == "tpu" else "off"
 
 
 def attention(q, k, v, *, causal: bool = True,
               sm_scale: Optional[float] = None,
               logits_soft_cap: Optional[float] = None):
     """Flash attention (prefill/training). See ref.attention for semantics."""
-    mode = pallas_mode()
-    if mode != "off":
-        from .flash_attention import flash_attention
+    if pallas_mode() == "tpu":
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               logits_soft_cap=logits_soft_cap,
-                               interpret=(mode == "interpret"))
+                               logits_soft_cap=logits_soft_cap)
     if q.shape[1] > 1024 or k.shape[1] > 1024:
         # Long sequences: flash-style blocked path so the lowered program
         # has O(S) memory and causal-proportional FLOPs (dry-run realism).
@@ -65,11 +54,8 @@ def attention(q, k, v, *, causal: bool = True,
 def decode_attention(q, k_cache, v_cache, lengths, *,
                      sm_scale: Optional[float] = None):
     """Flash-decode attention against a KV cache."""
-    mode = pallas_mode()
-    if mode != "off":
-        from .decode_attention import flash_decode
-        return flash_decode(q, k_cache, v_cache, lengths, sm_scale=sm_scale,
-                            interpret=(mode == "interpret"))
+    if pallas_mode() == "tpu":
+        return flash_decode(q, k_cache, v_cache, lengths, sm_scale=sm_scale)
     return ref.decode_attention(q, k_cache, v_cache, lengths,
                                 sm_scale=sm_scale)
 
@@ -78,34 +64,25 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            sm_scale: Optional[float] = None):
     """Flash-decode through a page table (continuous-batching serving).
 
-    See ref.paged_decode_attention for semantics. The Pallas path keeps
-    the contiguous kernel's grid — pages are block_k-sized blocks, the
-    table only changes the BlockSpec index map (scalar prefetch)."""
-    mode = pallas_mode()
-    if mode != "off":
-        from .decode_attention import flash_decode_paged
+    See ref.paged_decode_attention for semantics. The Pallas path is the
+    contiguous kernel with one page per block: the table only changes
+    the BlockSpec index map (scalar prefetch)."""
+    if pallas_mode() == "tpu":
         return flash_decode_paged(q, k_pages, v_pages, page_table, lengths,
-                                  sm_scale=sm_scale,
-                                  interpret=(mode == "interpret"))
+                                  sm_scale=sm_scale)
     return ref.paged_decode_attention(q, k_pages, v_pages, page_table,
                                       lengths, sm_scale=sm_scale)
 
 
 def rwkv6_scan(r, k, v, w, u, state=None):
     """RWKV6 WKV recurrence (chunked kernel on TPU)."""
-    mode = pallas_mode()
-    if mode != "off":
-        from .rwkv6_scan import rwkv6_chunked
-        return rwkv6_chunked(r, k, v, w, u, state,
-                             interpret=(mode == "interpret"))
+    if pallas_mode() == "tpu":
+        return rwkv6_chunked(r, k, v, w, u, state)
     return ref.rwkv6_scan(r, k, v, w, u, state)
 
 
 def mamba2_scan(x, dt, a, b, c, state=None):
     """Mamba2 SSD recurrence (chunked kernel on TPU)."""
-    mode = pallas_mode()
-    if mode != "off":
-        from .mamba2_scan import mamba2_chunked
-        return mamba2_chunked(x, dt, a, b, c, state,
-                              interpret=(mode == "interpret"))
+    if pallas_mode() == "tpu":
+        return mamba2_chunked(x, dt, a, b, c, state)
     return ref.mamba2_scan(x, dt, a, b, c, state)

@@ -23,12 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAVE_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 32
 
@@ -83,7 +78,7 @@ def rwkv6_chunked(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     s0 = state.reshape(B * H, D, D)
 
     kernel = functools.partial(_wkv_kernel, chunk=chunk)
-    scratch = [pltpu.VMEM((D, D), jnp.float32)] if _HAVE_PLTPU else None
+    scratch = [pltpu.VMEM((D, D), jnp.float32)]
     o, sT = pl.pallas_call(
         kernel,
         grid=(B * H, nc),

@@ -37,10 +37,10 @@ def _positions(B: int, S: int, offset: int = 0) -> jax.Array:
 
 
 def _stack_init(key, n: int, init_fn):
-    """Initialize n layers and stack leaves along a leading axis."""
-    keys = jax.random.split(key, n)
-    per_layer = [init_fn(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
+    """Initialize n layers and stack leaves along a leading axis. One
+    vmapped layer, not n unrolled ones: the values are the same, and the
+    jitted init of a 24-layer model compiles in seconds, not a minute."""
+    return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
 def scan_over(cfg: ModelConfig, body, carry, xs, length: int = None):

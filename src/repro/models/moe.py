@@ -185,7 +185,6 @@ def apply_moe(p: Params, cfg: ModelConfig, x: jax.Array):
     if rules is None or rules.axis_size(rules.tensor_axis) == 1:
         return apply_moe_gather(p, cfg, x, axis_name=None, axis_size=1)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     ta = rules.tensor_axis
     ba = rules.batch_axes
@@ -203,12 +202,12 @@ def apply_moe(p: Params, cfg: ModelConfig, x: jax.Array):
             cfg, x_loc, axis_name=ta, axis_size=tsize)
         return jax.lax.psum(y, ta), jax.lax.pmean(aux, all_axes)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe, mesh=rules.mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P(ta, None, None), P(ta, None, None), P(ta, None, None)),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, routed["router"], routed["wi"], routed["wg"], routed["wo"])
 
     if cfg.num_shared_experts:

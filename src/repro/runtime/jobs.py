@@ -213,7 +213,10 @@ class JobRunner:
             return 0
 
     def shutdown(self) -> None:
+        """Stop the workers and join them (each finishes the task it
+        holds), so none is still inside a computation, such as a JAX
+        call, when the caller's interpreter exits."""
         self._store.set(f"{self._tag}:stop", 1, ex=600)
         for _ in range(self.n_workers):
             self._store.rpush(f"{self._tag}:jobs", b"__stop__")
-        self._executor.shutdown(wait=False)
+        self._executor.shutdown(wait=True)

@@ -73,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import serialization
+from ..kernels.ops import check_page_size
 from ..models.model import Model
 from .paging import PageAllocator
 
@@ -232,6 +233,9 @@ class ContinuousEngine:
 
     See the module docstring for the admission / scheduling / eviction
     contract. Families: dense / vlm / moe (KV-cache caches only).
+    ``page_size`` is also the paged decode kernel's block: a size that
+    kernel cannot tile (see ``ops.check_page_size``; 1-512 at 16 query
+    and 16 kv heads) raises ValueError here, not at the first compile.
     """
 
     def __init__(self, model: Model, params, *, max_slots: int = 4,
@@ -240,6 +244,8 @@ class ContinuousEngine:
                  eos_id: Optional[int] = None, request_queue=None,
                  lease: bool = False, lease_ttl_s: float = 30.0,
                  worker_id: Optional[str] = None):
+        check_page_size(page_size, model.cfg.num_heads,
+                        model.cfg.num_kv_heads)
         self.model = model
         self.params = params
         self.max_slots = max_slots

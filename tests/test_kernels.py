@@ -24,6 +24,8 @@ ATTN_SWEEP = [
     (1, 256, 4, 2, 112, True, jnp.float32),   # kimi head dim (pad to 128)
     (2, 256, 8, 4, 64, True, jnp.bfloat16),
     (1, 64, 2, 1, 64, True, jnp.float32),     # MHA==GQA(1)
+    (1, 600, 4, 2, 64, True, jnp.float32),    # S not a multiple of the block
+    (1, 600, 4, 2, 64, False, jnp.float32),   # ... and padded keys masked
 ]
 
 
@@ -56,16 +58,23 @@ class TestFlashAttention:
                                        np.array(o2, np.float32),
                                        atol=2e-5, rtol=2e-5)
 
-    def test_gradients_match_oracle(self):
-        B, S, H, K, D = 1, 128, 4, 2, 64
+    @pytest.mark.parametrize("S,D,causal", [
+        (128, 64, True),
+        # S=200 with 64-blocks: the padded rows and key columns add
+        # nothing to any gradient
+        (200, 32, True),
+        (200, 32, False),
+    ])
+    def test_gradients_match_oracle(self, S, D, causal):
+        B, H, K = 1, 4, 2
         q, k, v = _qkv(B, S, H, K, D, jnp.float32)
 
         def loss_flash(q, k, v):
-            return (flash_attention(q, k, v, causal=True, block_q=64,
+            return (flash_attention(q, k, v, causal=causal, block_q=64,
                                     block_k=64, interpret=True) ** 2).sum()
 
         def loss_ref(q, k, v):
-            return (ref.attention(q, k, v, causal=True) ** 2).sum()
+            return (ref.attention(q, k, v, causal=causal) ** 2).sum()
 
         g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -188,6 +197,23 @@ class TestPagedDecode:
         np.testing.assert_allclose(np.array(o_pallas), np.array(o_ref),
                                    atol=2e-5, rtol=2e-5)
         np.testing.assert_allclose(np.array(o_jnp), np.array(o_ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("page", [16, 128])
+    def test_paged_at_serving_widths(self, page):
+        """qwen1.5-0.5b's attention widths (K=16 kv heads, hd=64) at
+        the page sizes the engine serves with."""
+        B, S, H, K, D = 2, 256, 16, 16, 64
+        ks = jax.random.split(KEY, 3)
+        q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
+        kc = jax.random.normal(ks[1], (B, S, K, D), jnp.float32)
+        vc = jax.random.normal(ks[2], (B, S, K, D), jnp.float32)
+        lengths = jnp.asarray([S, page + 3], jnp.int32)
+        kp, vp, table = self._paged_from_contiguous(
+            kc, vc, page, B * (S // page) + 1)
+        o_ref = ref.decode_attention(q, kc, vc, lengths)
+        o = flash_decode_paged(q, kp, vp, table, lengths, interpret=True)
+        np.testing.assert_allclose(np.array(o), np.array(o_ref),
                                    atol=2e-5, rtol=2e-5)
 
     def test_gather_round_trip(self):

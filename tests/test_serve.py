@@ -197,6 +197,14 @@ class TestContinuousEngine:
             row = _static_row(m, params, toks, 8, max_len=32)
             assert eng.results[rid]["tokens"] == list(row)
 
+    @pytest.mark.parametrize("page_size", [0, 2048])
+    def test_illegal_page_size_rejected(self, model_params, page_size):
+        """The page is the decode kernel's block: a size it cannot tile
+        fails at construction, not at the first compile."""
+        m, params = model_params
+        with pytest.raises(ValueError, match="page_size"):
+            ContinuousEngine(m, params, page_size=page_size)
+
     def test_oversize_request_rejected(self, model_params):
         m, params = model_params
         eng = ContinuousEngine(m, params, max_slots=2, page_size=8,
