@@ -93,8 +93,9 @@ def require_tpu():
 def kernel_inputs(seed: int) -> dict:
     """Seeded bf16 inputs of the three kernels at the served shapes:
     decode over 8 slots of 2048 positions (ragged lengths, one full row
-    and one of length 3) from a shuffled page table, and the causal
-    prefill of one 1000-token prompt, not a multiple of the 512 block."""
+    and one of length 3) from a shuffled page table into the last layer
+    of a two-layer slab ``[2, P, page, K * D]``, and the causal prefill
+    of one 1000-token prompt, not a multiple of the 512 block."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -114,13 +115,15 @@ def kernel_inputs(seed: int) -> dict:
     P = SLOTS * M + 1
     table = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(SLOTS, M),
                         jnp.int32)
-    kp, vp = normal(ks[1], (P, PAGE, K, D)), normal(ks[2], (P, PAGE, K, D))
+    kp = normal(ks[1], (2, P, PAGE, K * D))
+    vp = normal(ks[2], (2, P, PAGE, K * D))
+    layer = jnp.int32(1)
     S = 1000
     return dict(
         q=normal(ks[0], (SLOTS, H, D), 2.0), k_pages=kp, v_pages=vp,
-        table=table, lengths=jnp.asarray(lengths, jnp.int32),
-        k_cache=kp[table].reshape(SLOTS, MAX_LEN, K, D),
-        v_cache=vp[table].reshape(SLOTS, MAX_LEN, K, D),
+        layer=layer, table=table, lengths=jnp.asarray(lengths, jnp.int32),
+        k_cache=kp[layer, table].reshape(SLOTS, MAX_LEN, K, D),
+        v_cache=vp[layer, table].reshape(SLOTS, MAX_LEN, K, D),
         q_prefill=normal(ks[3], (1, S, H, D), 2.0),
         k_prefill=normal(ks[4], (1, S, K, D)),
         v_prefill=normal(ks[5], (1, S, K, D)))
@@ -161,7 +164,8 @@ def check_kernels(seed: int) -> None:
     from repro.kernels.flash_attention import flash_attention
 
     x = kernel_inputs(seed)
-    paged = (x["q"], x["k_pages"], x["v_pages"], x["table"], x["lengths"])
+    paged = (x["q"], x["k_pages"], x["v_pages"], x["layer"], x["table"],
+             x["lengths"])
     dense = (x["q"], x["k_cache"], x["v_cache"], x["lengths"])
     prefill = (x["q_prefill"], x["k_prefill"], x["v_prefill"])
     for name, tol, got, want in (

@@ -140,8 +140,8 @@ def readings(seed: int) -> list:
     dec, pre = smoke.DECODE_TOL, smoke.PREFILL_TOL
     cases = [
         ("decode kernel flash_decode_paged", dec, True, want_d,
-         flash_decode_paged(q, x["k_pages"], x["v_pages"], x["table"],
-                            lengths, interpret=interpret)),
+         flash_decode_paged(q, x["k_pages"], x["v_pages"], x["layer"],
+                            x["table"], lengths, interpret=interpret)),
         ("decode kernel flash_decode", dec, True, want_d,
          flash_decode(q, kc, vc, lengths, interpret=interpret)),
         ("decode online f32 (emulation check)", dec, True, want_d,

@@ -23,11 +23,18 @@ valid block, so blocks past the length are neither computed (``pl.when``)
 nor fetched again (an unchanged block index skips the copy). Rows with
 ``lengths == 0`` emit zeros: the accumulator never runs.
 
-:func:`flash_decode_paged` is the same kernel gathering K/V through a
-per-sequence **page table**: a block is one page, ``[page, K, hd]``, and
-the index map resolves block ``j`` of sequence ``b`` to slab page
-``page_table[b, j]`` (also scalar-prefetched), so the page indirection
-costs no extra DMA step.
+:func:`flash_decode_paged` reads K/V through a per-sequence **page
+table** from the serving slab ``[L, P, page, K * hd]``, whole: the
+layer's index and the table arrive by scalar prefetch, and the K/V index
+map resolves block ``j`` of sequence ``b`` to ``(layer, page_table[b,
+j])``, so neither the layer slice nor the page indirection costs a copy.
+A block is one page of all kv heads, ``[page, K * hd]``: the minor dim
+is lane-dense (a multiple of 128 at every served width), which is also
+XLA's compact layout of the slab, so the kernel, the engine's scatter
+and the stored slab share one layout. Per-head scores come from a
+lane-dense multiply and an NT matmul with the 0/1 head indicator
+``[K, K * hd]``; the same indicator spreads each head's probabilities
+and softmax state back over its lanes.
 """
 
 from __future__ import annotations
@@ -42,26 +49,27 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_K = 128
-#: Largest ``block_k * G * K`` (K rounded up to the 8-row f32 tile) whose
-#: f32 working set fits scoped VMEM. Measured by compiling
-#: ``flash_decode_paged`` for v5e at the repo's configs' widths, hd <= 128
-#: (largest page that compiled, first that did not): K=16 G=1 1024/2048,
-#: K=20 G=1 1024/2048, K=32 G=1 512/1024, K=36 G=1 512/1024, K=8 G=2
-#: and G=4 512/1024, K=8 G=8 256/512. The bound admits no page above the
-#: largest that compiled at each width; wider heads are unmeasured.
-MAX_BLOCK_ROWS = 8192
+#: Largest ``page * K * hd`` (``K * hd`` rounded up to 128 lanes) whose
+#: f32 working set fits scoped VMEM: the paged kernel's block is one page
+#: of all kv heads, ``[page, K * hd]``, and the query groups run one at a
+#: time. Measured by compiling ``flash_decode_paged`` for v5e (largest
+#: page that compiled, first that did not): K*hd 1024 at G = 1, 2, 4
+#: 1024/2048; K*hd 2560 at G = 1 512/768; K*hd 2304 at G = 1 512/1024;
+#: K*hd 896 at G = 8 1024 compiled. Every page the bound admits at the
+#: configs' widths compiled (1024, 1170, 409, 455).
+MAX_BLOCK_ELEMS = 1 << 20
 
 
-def check_page_size(page_size: int, num_heads: int,
-                    num_kv_heads: int) -> None:
+def check_page_size(page_size: int, num_kv_heads: int,
+                    head_dim: int) -> None:
     """Raise ValueError unless ``flash_decode_paged`` can take this page:
-    a page is one kernel block ``[page, K, hd]``, bounded only by VMEM."""
-    rows = (num_heads // num_kv_heads) * (-(-num_kv_heads // 8) * 8)
-    limit = MAX_BLOCK_ROWS // rows
+    a page is one kernel block ``[page, K * hd]``, bounded only by VMEM."""
+    lanes = -(-num_kv_heads * head_dim // 128) * 128
+    limit = MAX_BLOCK_ELEMS // lanes
     if not 1 <= page_size <= limit:
         raise ValueError(
             f"page_size={page_size} is not a legal paged-decode block for "
-            f"{num_heads} query / {num_kv_heads} kv heads: use 1..{limit}")
+            f"{num_kv_heads} kv heads of {head_dim}: use 1..{limit}")
 
 
 def _last_block(length, block_k: int):
@@ -164,26 +172,119 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                         interpret)
 
 
+def _head_indicator(K: int, KD: int, head_dim: int) -> jax.Array:
+    """``[K, KD]`` f32: 1 where lane ``i`` belongs to kv head ``k``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (K, KD), 1)
+    first = jax.lax.broadcasted_iota(jnp.int32, (K, KD), 0) * head_dim
+    return ((lane >= first) & (lane < first + head_dim)).astype(jnp.float32)
+
+
+def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
+                  m_scr, l_scr, acc_scr, *, page: int, head_dim: int,
+                  sm_scale: float):
+    del layer_ref, tbl_ref  # read by the index maps only
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = len_ref[b]
+    G, KD = q_ref.shape[1], q_ref.shape[3]
+    K = KD // head_dim
+
+    @pl.when(j == 0)
+    def init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * page < length)
+    def compute():
+        k = k_ref[0, 0].astype(jnp.float32)                # [page, KD]
+        v = v_ref[0, 0].astype(jnp.float32)
+        ind = _head_indicator(K, KD, head_dim)
+        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
+        valid = pos < length                               # [page, 1]
+        hi = jax.lax.Precision.HIGHEST
+
+        def group(g, carry):
+            q = q_ref[0, g].astype(jnp.float32) * sm_scale  # [1, KD]
+            s = jax.lax.dot_general(
+                k * q, ind, (((1,), (1,)), ((), ())), precision=hi,
+                preferred_element_type=jnp.float32)        # [page, K]
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_scr[g]                              # [1, K]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                         # [page, K]
+            l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            p_lanes = jnp.dot(p, ind, precision=hi,
+                              preferred_element_type=jnp.float32)
+            a_lanes = jnp.dot(alpha, ind, precision=hi,
+                              preferred_element_type=jnp.float32)
+            acc_scr[g] = acc_scr[g] * a_lanes + jnp.sum(
+                p_lanes * v, axis=0, keepdims=True)
+            m_scr[g] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, G, group, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def emit():
+        ind = _head_indicator(K, KD, head_dim)
+        for g in range(G):
+            l = jnp.dot(jnp.maximum(l_scr[g], 1e-30), ind,
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)  # [1, KD]
+            o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
+
+
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                       page_table: jax.Array, lengths: jax.Array, *,
+                       layer, page_table: jax.Array, lengths: jax.Array, *,
                        sm_scale: Optional[float] = None,
                        interpret: bool = False) -> jax.Array:
-    """Flash-decode gathering K/V through a page table.
+    """Flash-decode of one layer, gathering K/V through a page table.
 
-    q: [B, H, D]; k_pages, v_pages: [P, page_size, K, D] (shared slab,
-    page 0 reserved as the null page); page_table: [B, M] int32;
-    lengths: [B] -> [B, H, D]. Token ``t`` of sequence ``b`` lives at
-    ``(page_table[b, t // page_size], t % page_size)``; table entries at
-    or past ``ceil(lengths[b] / page_size)`` are never read.
+    q: [B, H, D]; k_pages, v_pages: [L, P, page_size, K * D] (the whole
+    serving slab, page 0 of every layer reserved as the null page);
+    layer: int32 scalar, the layer to read; page_table: [B, M] int32;
+    lengths: [B] -> [B, H, D]. Head ``kv`` of token ``t`` of sequence
+    ``b`` lives at ``[layer, page_table[b, t // page_size], t %
+    page_size, kv * D:(kv + 1) * D]``; table entries at or past
+    ``ceil(lengths[b] / page_size)`` are never read. Query head ``h``
+    reads kv head ``h // (H // K)``.
     """
-    page_size = k_pages.shape[1]
+    B, H, D = q.shape
+    _, _, page, KD = k_pages.shape
+    K = KD // D
+    G = H // K
+    scale = sm_scale if sm_scale is not None else D ** -0.5
+    # group-major queries on the slab's lanes: qg[b, g, 0, kv * D + d]
+    # is query head kv * G + g
+    qg = jnp.swapaxes(q.reshape(B, K, G, D), 1, 2).reshape(B, G, 1, KD)
 
-    def kv_map(b, j, lens, tbl):
-        return (tbl[b, jnp.minimum(j, _last_block(lens[b], page_size))],
-                0, 0, 0)
+    def kv_map(b, j, lyr, lens, tbl):
+        return (lyr[0], tbl[b, jnp.minimum(j, _last_block(lens[b], page))],
+                0, 0)
 
-    return _decode_call(q, k_pages, v_pages,
-                        (lengths.astype(jnp.int32),
-                         page_table.astype(jnp.int32)),
-                        kv_map, page_table.shape[1], page_size, sm_scale,
-                        interpret)
+    def qo_map(b, j, *_):
+        return (b, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, page_table.shape[1]),
+        in_specs=[pl.BlockSpec((1, G, 1, KD), qo_map),
+                  pl.BlockSpec((1, 1, page, KD), kv_map),
+                  pl.BlockSpec((1, 1, page, KD), kv_map)],
+        out_specs=pl.BlockSpec((1, G, 1, KD), qo_map),
+        scratch_shapes=[pltpu.VMEM((G, 1, K), jnp.float32),
+                        pltpu.VMEM((G, 1, K), jnp.float32),
+                        pltpu.VMEM((G, 1, KD), jnp.float32)],
+    )
+    kernel = functools.partial(_paged_kernel, page=page, head_dim=D,
+                               sm_scale=scale)
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, G, 1, KD), q.dtype),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
+      page_table.astype(jnp.int32), qg, k_pages, v_pages)
+    return jnp.swapaxes(o.reshape(B, G, K, D), 1, 2).reshape(B, H, D)

@@ -60,18 +60,19 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
                                 sm_scale=sm_scale)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
-                           sm_scale: Optional[float] = None):
-    """Flash-decode through a page table (continuous-batching serving).
+def paged_decode_attention(q, k_pages, v_pages, layer, page_table, lengths,
+                           *, sm_scale: Optional[float] = None):
+    """Flash-decode of one layer through a page table (continuous-
+    batching serving), reading the whole slab ``[L, P, page, K * hd]``.
 
-    See ref.paged_decode_attention for semantics. The Pallas path is the
-    contiguous kernel with one page per block: the table only changes
-    the BlockSpec index map (scalar prefetch)."""
+    See ref.paged_decode_attention for semantics. The Pallas path takes
+    one page of all kv heads per block; the layer index and the table
+    only change the BlockSpec index map (scalar prefetch)."""
     if pallas_mode() == "tpu":
-        return flash_decode_paged(q, k_pages, v_pages, page_table, lengths,
-                                  sm_scale=sm_scale)
-    return ref.paged_decode_attention(q, k_pages, v_pages, page_table,
-                                      lengths, sm_scale=sm_scale)
+        return flash_decode_paged(q, k_pages, v_pages, layer, page_table,
+                                  lengths, sm_scale=sm_scale)
+    return ref.paged_decode_attention(q, k_pages, v_pages, layer,
+                                      page_table, lengths, sm_scale=sm_scale)
 
 
 def rwkv6_scan(r, k, v, w, u, state=None):

@@ -171,26 +171,27 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, page_table: jax.Array,
+                           v_pages: jax.Array, layer, page_table: jax.Array,
                            lengths: jax.Array, *,
                            sm_scale: Optional[float] = None) -> jax.Array:
-    """Decode attention through a paged KV cache (the oracle).
+    """Decode attention of one layer through the paged KV slab (the oracle).
 
-    q: [B, H, D]; k_pages, v_pages: [P, page_size, K, D] — the shared
-    page slab; page_table: [B, M] int32 — per-sequence page ids (entries
-    past the allocated prefix point at the reserved null page 0 and are
-    masked by ``lengths``); lengths: [B] valid tokens. Token ``t`` of
-    sequence ``b`` lives at ``(page_table[b, t // page_size],
-    t % page_size)``. Gathers each sequence's pages into the contiguous
-    [B, M * page_size, K, D] view and defers to :func:`decode_attention`,
-    so paged and contiguous decode are numerically identical by
-    construction.
+    q: [B, H, D]; k_pages, v_pages: [L, P, page_size, K * D] — the whole
+    serving slab, kv heads side by side on the minor dim; layer: the
+    layer to read; page_table: [B, M] int32 — per-sequence page ids
+    (entries past the allocated prefix point at the reserved null page 0
+    and are masked by ``lengths``); lengths: [B] valid tokens. Head
+    ``kv`` of token ``t`` of sequence ``b`` lives at ``[layer,
+    page_table[b, t // page_size], t % page_size, kv * D:(kv + 1) * D]``.
+    Gathers each sequence's pages into the contiguous [B, M * page_size,
+    K, D] view and defers to :func:`decode_attention`, so paged and
+    contiguous decode are numerically identical by construction.
     """
-    B = q.shape[0]
-    _, page_size, K, D = k_pages.shape
-    M = page_table.shape[1]
-    kc = k_pages[page_table].reshape(B, M * page_size, K, D)
-    vc = v_pages[page_table].reshape(B, M * page_size, K, D)
+    B, _, D = q.shape
+    page_size, KD = k_pages.shape[2:]
+    S = page_table.shape[1] * page_size
+    kc = k_pages[layer, page_table].reshape(B, S, KD // D, D)
+    vc = v_pages[layer, page_table].reshape(B, S, KD // D, D)
     return decode_attention(q, kc, vc, lengths, sm_scale=sm_scale)
 
 

@@ -189,71 +189,81 @@ def apply_attention_decode(p: Params, cfg: ModelConfig, x: jax.Array,
 
 def apply_attention_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
                                  k_pages: jax.Array, v_pages: jax.Array,
-                                 page_table: jax.Array, lengths: jax.Array,
-                                 slot_mask: jax.Array,
+                                 layer, page_table: jax.Array,
+                                 lengths: jax.Array, slot_mask: jax.Array,
                                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode against the shared page slab (continuous batching).
+    """One-token decode of layer ``layer`` against the shared page slab.
 
-    x: [B, 1, D]; k_pages/v_pages: [P, page, K, hd] — ONE slab shared by
-    every sequence, page 0 reserved as the null page; page_table: [B, M]
-    per-slot page ids; lengths: [B] valid cache entries (the new token is
-    written at position ``lengths``); slot_mask: [B] bool — False rows
-    are idle serving slots: their K/V write is redirected to the null
-    page and their attention length forced to 0, so a dead slot can
-    neither corrupt a live sequence's pages nor read stale ones.
+    x: [B, 1, D]; k_pages/v_pages: [L, P, page, K * hd] — the WHOLE slab,
+    shared by every sequence, page 0 reserved as the null page; layer:
+    int32 scalar; page_table: [B, M] per-slot page ids; lengths: [B]
+    valid cache entries (the new token is written at position
+    ``lengths``); slot_mask: [B] bool — False rows are idle serving
+    slots: their K/V write is redirected to the null page and their
+    attention length forced to 0, so a dead slot can neither corrupt a
+    live sequence's pages nor read stale ones. The write is one scatter
+    into the slab as passed in, so a donated slab carried through the
+    layer loop is updated in place.
 
     Equivalent to ``apply_attention_decode`` with the "onehot" policy on
     the gathered contiguous cache — per-slot ragged lengths (and thus
     ragged rope positions) are the normal case here, not an edge case.
     """
     B = x.shape[0]
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     q, k, v = _project_qkv(p, cfg, x, lengths[:, None], use_rope=True)
     pid = page_table[jnp.arange(B), lengths // page]           # [B]
     pid = jnp.where(slot_mask, pid, 0)
     off = lengths % page
-    k_pages = k_pages.at[pid, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[pid, off].set(v[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[layer, pid, off].set(
+        k.reshape(B, -1).astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, pid, off].set(
+        v.reshape(B, -1).astype(v_pages.dtype))
     att_len = jnp.where(slot_mask, lengths + 1, 0)
-    o = ops.paged_decode_attention(q[:, 0], k_pages, v_pages, page_table,
-                                   att_len)
+    o = ops.paged_decode_attention(q[:, 0], k_pages, v_pages, layer,
+                                   page_table, att_len)
     out = jnp.einsum("bf,fd->bd", o.reshape(B, -1), p["wo"])[:, None, :]
     return out, k_pages, v_pages
 
 
 def apply_attention_prefill_paged(p: Params, cfg: ModelConfig, x: jax.Array,
                                   k_pages: jax.Array, v_pages: jax.Array,
-                                  page_table: jax.Array, start: jax.Array,
-                                  n_valid: jax.Array,
+                                  layer, page_table: jax.Array,
+                                  start: jax.Array, n_valid: jax.Array,
                                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Chunked prefill attention for ONE request writing into the slab.
+    """Chunked prefill attention of layer ``layer`` for ONE request
+    writing into the slab.
 
     x: [1, C, D] — the next chunk of the prompt, padded to the static
-    chunk length C; page_table: [M] (this request's row); start: tokens
-    already cached by earlier chunks; n_valid: real tokens in this chunk
-    (the tail past it is padding: its K/V writes are redirected to the
-    null page and no valid query row can attend that far right).
+    chunk length C; k_pages/v_pages: [L, P, page, K * hd], the whole
+    slab; page_table: [M] (this request's row); start: tokens already
+    cached by earlier chunks; n_valid: real tokens in this chunk (the
+    tail past it is padding: its K/V writes are redirected to the null
+    page and no valid query row can attend that far right).
 
     The chunk's K/V are scattered into the pages FIRST, then the
-    request's whole window is gathered back ([M * page] positions) and
-    attended causally with the shifted mask ``col <= start + row`` —
-    exactly ``ops.attention``'s semantics continued from a cache, f32
-    softmax and all, so chunked prefill matches one-shot prefill.
+    request's whole window of this layer is gathered back ([M * page]
+    positions) and attended causally with the shifted mask ``col <=
+    start + row`` — exactly ``ops.attention``'s semantics continued from
+    a cache, f32 softmax and all, so chunked prefill matches one-shot
+    prefill.
     """
     _, C, _ = x.shape
-    P, page, K, hd = k_pages.shape
+    page = k_pages.shape[2]
     M = page_table.shape[0]
-    H = cfg.num_heads
+    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     G = H // K
     tpos = start + jnp.arange(C, dtype=jnp.int32)              # [C]
     q, k, v = _project_qkv(p, cfg, x, tpos[None], use_rope=True)
     valid = jnp.arange(C) < n_valid
     pid = jnp.where(valid, page_table[tpos // page], 0)
     off = tpos % page
-    k_pages = k_pages.at[pid, off].set(k[0].astype(k_pages.dtype))
-    v_pages = v_pages.at[pid, off].set(v[0].astype(v_pages.dtype))
-    kc = k_pages[page_table].reshape(M * page, K, hd)
-    vc = v_pages[page_table].reshape(M * page, K, hd)
+    k_pages = k_pages.at[layer, pid, off].set(
+        k[0].reshape(C, -1).astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, pid, off].set(
+        v[0].reshape(C, -1).astype(v_pages.dtype))
+    kc = k_pages[layer, page_table].reshape(M * page, K, hd)
+    vc = v_pages[layer, page_table].reshape(M * page, K, hd)
     scale = hd ** -0.5
     qf = q.astype(jnp.float32).reshape(C, K, G, hd) * scale
     logits = jnp.einsum("qkgd,skd->kgqs", qf, kc.astype(jnp.float32))
@@ -265,28 +275,6 @@ def apply_attention_prefill_paged(p: Params, cfg: ModelConfig, x: jax.Array,
     o = o.reshape(1, C, H * hd).astype(x.dtype)
     out = jnp.einsum("bsf,fd->bsd", o, p["wo"])
     return out, k_pages, v_pages
-
-
-def apply_dense_block_decode_paged(p, cfg, x, k_pages, v_pages, page_table,
-                                   lengths, slot_mask):
-    r = cfg.residual_scale
-    a, kp, vp = apply_attention_decode_paged(
-        p["attn"], cfg, rms_norm(x, p["norm1"], cfg.norm_eps),
-        k_pages, v_pages, page_table, lengths, slot_mask)
-    x = x + r * a
-    x = x + r * apply_mlp(p["mlp"], rms_norm(x, p["norm2"], cfg.norm_eps))
-    return x, kp, vp
-
-
-def apply_dense_block_prefill_paged(p, cfg, x, k_pages, v_pages, page_table,
-                                    start, n_valid):
-    r = cfg.residual_scale
-    a, kp, vp = apply_attention_prefill_paged(
-        p["attn"], cfg, rms_norm(x, p["norm1"], cfg.norm_eps),
-        k_pages, v_pages, page_table, start, n_valid)
-    x = x + r * a
-    x = x + r * apply_mlp(p["mlp"], rms_norm(x, p["norm2"], cfg.norm_eps))
-    return x, kp, vp
 
 
 def init_cross_attention(key, cfg: ModelConfig) -> Params:

@@ -427,6 +427,9 @@ class Model:
     # a single page slab shared by every serving slot; per-slot page
     # tables map token position t to (table[t // page], t % page). Page 0
     # is reserved as the null page. Only KV-cache families support this.
+    # The layer loop carries the slab whole and writes each layer's new
+    # K/V into it in place: handing a layer's slice through the scan as
+    # xs/ys would slice and write back the slab on every layer.
 
     def _check_paged(self):
         if self.cfg.family not in ("dense", "vlm", "moe"):
@@ -436,14 +439,44 @@ class Model:
 
     def init_paged_cache(self, num_pages: int, page_size: int
                          ) -> Dict[str, jax.Array]:
-        """Zeroed page slab: {'k_pages','v_pages': [L, P, page, K, hd]}."""
+        """Zeroed page slab: {'k_pages','v_pages': [L, P, page, K * hd]},
+        the kv heads side by side on the lane-dense minor dim."""
         self._check_paged()
         cfg = self.cfg
         shape = (cfg.num_layers, num_pages, page_size,
-                 cfg.num_kv_heads, cfg.hd)
+                 cfg.num_kv_heads * cfg.hd)
         dt = cfg.act_dtype()
         return {"k_pages": jnp.zeros(shape, dt),
                 "v_pages": jnp.zeros(shape, dt)}
+
+    def _paged_layers(self, params: Params, x: jax.Array,
+                      pages: Dict[str, jax.Array], attend
+                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """Run the stacked layers over the slab. ``attend(attn_params, h,
+        k_pages, v_pages, layer) -> (a, k_pages, v_pages)`` is the
+        layer's attention against the whole slab."""
+        cfg = self.cfg
+        # the moe block has no residual scale, as in ``_backbone``
+        r = 1.0 if cfg.family == "moe" else cfg.residual_scale
+
+        def body(carry, xs):
+            h, kp, vp = carry
+            layer, i = xs
+            a, kp, vp = attend(layer["attn"], L.rms_norm(
+                h, layer["norm1"], cfg.norm_eps), kp, vp, i)
+            h = h + r * a
+            hn = L.rms_norm(h, layer["norm2"], cfg.norm_eps)
+            if cfg.family == "moe":
+                mo, _ = X.apply_moe(layer["moe"], cfg, hn)
+            else:
+                mo = L.apply_mlp(layer["mlp"], hn)
+            return (h + r * mo, kp, vp), None
+
+        idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+        (x, kp, vp), _ = scan_over(
+            cfg, body, (x, pages["k_pages"], pages["v_pages"]),
+            (params["layers"], idx))
+        return x, {"k_pages": kp, "v_pages": vp}
 
     def decode_paged(self, params: Params, pages: Dict[str, jax.Array],
                      tokens: jax.Array, page_tables: jax.Array,
@@ -459,32 +492,16 @@ class Model:
         """
         self._check_paged()
         cfg = self.cfg
-        fam = cfg.family
         x = L.embed(params["embed"], cfg, tokens[:, None])
 
-        def body(h, xs):
-            layer, kp, vp = xs
-            if fam == "moe":
-                a, nk, nv = L.apply_attention_decode_paged(
-                    layer["attn"], cfg,
-                    L.rms_norm(h, layer["norm1"], cfg.norm_eps),
-                    kp, vp, page_tables, lengths, slot_mask)
-                h = h + a
-                mo, _ = X.apply_moe(
-                    layer["moe"], cfg,
-                    L.rms_norm(h, layer["norm2"], cfg.norm_eps))
-                h = h + mo
-            else:
-                h, nk, nv = L.apply_dense_block_decode_paged(
-                    layer, cfg, h, kp, vp, page_tables, lengths, slot_mask)
-            return h, (nk, nv)
+        def attend(p, h, kp, vp, i):
+            return L.apply_attention_decode_paged(
+                p, cfg, h, kp, vp, i, page_tables, lengths, slot_mask)
 
-        x, (nks, nvs) = scan_over(
-            cfg, body, x,
-            (params["layers"], pages["k_pages"], pages["v_pages"]))
+        x, pages = self._paged_layers(params, x, pages, attend)
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = L.unembed(params["embed"], cfg, x)[:, 0]
-        return logits, {"k_pages": nks, "v_pages": nvs}
+        return logits, pages
 
     def prefill_paged_chunk(self, params: Params,
                             pages: Dict[str, jax.Array],
@@ -501,33 +518,17 @@ class Model:
         """
         self._check_paged()
         cfg = self.cfg
-        fam = cfg.family
         x = L.embed(params["embed"], cfg, tokens)
 
-        def body(h, xs):
-            layer, kp, vp = xs
-            if fam == "moe":
-                a, nk, nv = L.apply_attention_prefill_paged(
-                    layer["attn"], cfg,
-                    L.rms_norm(h, layer["norm1"], cfg.norm_eps),
-                    kp, vp, page_table, start, n_valid)
-                h = h + a
-                mo, _ = X.apply_moe(
-                    layer["moe"], cfg,
-                    L.rms_norm(h, layer["norm2"], cfg.norm_eps))
-                h = h + mo
-            else:
-                h, nk, nv = L.apply_dense_block_prefill_paged(
-                    layer, cfg, h, kp, vp, page_table, start, n_valid)
-            return h, (nk, nv)
+        def attend(p, h, kp, vp, i):
+            return L.apply_attention_prefill_paged(
+                p, cfg, h, kp, vp, i, page_table, start, n_valid)
 
-        x, (nks, nvs) = scan_over(
-            cfg, body, x,
-            (params["layers"], pages["k_pages"], pages["v_pages"]))
+        x, pages = self._paged_layers(params, x, pages, attend)
         last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         last = L.rms_norm(last, params["final_norm"], cfg.norm_eps)
         logits = L.unembed(params["embed"], cfg, last)[:, 0]
-        return logits, {"k_pages": nks, "v_pages": nvs}
+        return logits, pages
 
     def decode(self, params: Params, cache: Dict[str, Any],
                tokens: jax.Array) -> Tuple[jax.Array, Dict[str, Any]]:

@@ -44,10 +44,13 @@ attention length) until its prompt completes.
 
 Page table layout & eviction contract
 -------------------------------------
-The KV cache is a shared slab ``[L, num_pages, page_size, K, hd]``;
-token ``t`` of the request in slot ``b`` lives at page
+The KV cache is a shared slab ``[L, num_pages, page_size, K * hd]``
+for keys and one for values, the kv heads side by side on the minor
+dim; token ``t`` of the request in slot ``b`` lives at page
 ``table[b, t // page_size]``, offset ``t % page_size``. Page 0 is the
 null page (never referenced by a live table; absorbs masked writes).
+The decode and prefill steps take the slab donated (on a TPU) and carry
+it whole through the layer loop, writing each layer's new K/V in place.
 Pages are allocated at admission (enough for the prompt) and grown one
 page at a time when decode crosses a page boundary. On eos or on
 reaching ``max_new_tokens`` the slot's pages return to the free list
@@ -141,6 +144,30 @@ def make_prefill(model: Model, max_len: int):
     def prefill(params, batch):
         return model.prefill(params, batch, max_len)
     return prefill
+
+
+def make_decode_step(model: Model):
+    """decode_step(params, pages, tokens, tables, lengths, mask) ->
+    (next_tokens, pages): ``ContinuousEngine``'s decode program."""
+
+    def decode_step(params, pages, tokens, tables, lengths, mask):
+        logits, pages = model.decode_paged(params, pages, tokens, tables,
+                                           lengths, mask)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
+
+    return decode_step
+
+
+def make_prefill_step(model: Model):
+    """prefill_step(params, pages, tokens, table, start, n_valid) ->
+    (next_token, pages): ``ContinuousEngine``'s prompt-chunk program."""
+
+    def prefill_step(params, pages, tokens, table, start, n_valid):
+        logits, pages = model.prefill_paged_chunk(params, pages, tokens,
+                                                  table, start, n_valid)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
+
+    return prefill_step
 
 
 def make_serve_step(model: Model, greedy: bool = True):
@@ -298,8 +325,8 @@ class ContinuousEngine:
     See the module docstring for the admission / scheduling / eviction
     contract. Families: dense / vlm / moe (KV-cache caches only).
     ``page_size`` is also the paged decode kernel's block: a size that
-    kernel cannot tile (see ``ops.check_page_size``; 1-512 at 16 query
-    and 16 kv heads) raises ValueError here, not at the first compile.
+    kernel cannot tile (see ``ops.check_page_size``; 1-1024 at 16 kv
+    heads of 64) raises ValueError here, not at the first compile.
     """
 
     def __init__(self, model: Model, params, *, max_slots: int = 4,
@@ -308,8 +335,7 @@ class ContinuousEngine:
                  eos_id: Optional[int] = None, request_queue=None,
                  lease: bool = False, lease_ttl_s: float = 30.0,
                  worker_id: Optional[str] = None):
-        check_page_size(page_size, model.cfg.num_heads,
-                        model.cfg.num_kv_heads)
+        check_page_size(page_size, model.cfg.num_kv_heads, model.cfg.hd)
         self.model = model
         self.params = params
         self.max_slots = max_slots
@@ -343,19 +369,11 @@ class ContinuousEngine:
                         "rejected": 0, "decode_steps": 0,
                         "prefill_chunks": 0}
 
-        def decode_step(params, pages, tokens, tables, lengths, mask):
-            logits, pages = model.decode_paged(params, pages, tokens,
-                                               tables, lengths, mask)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
-
-        def prefill_step(params, pages, tokens, table, start, n_valid):
-            logits, pages = model.prefill_paged_chunk(params, pages, tokens,
-                                                      table, start, n_valid)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
-
         donate = (1,) if jax.default_backend() == "tpu" else ()
-        self._decode = jax.jit(decode_step, donate_argnums=donate)
-        self._prefill_chunk = jax.jit(prefill_step, donate_argnums=donate)
+        self._decode = jax.jit(make_decode_step(model),
+                               donate_argnums=donate)
+        self._prefill_chunk = jax.jit(make_prefill_step(model),
+                                      donate_argnums=donate)
 
     # ------------------------------------------------------------- metrics
 

@@ -160,49 +160,62 @@ class TestFlashDecode:
 
 class TestPagedDecode:
     """Paged flash-decode vs the contiguous oracle: scatter a contiguous
-    cache into a randomly-permuted page slab and the outputs must match
+    cache into one layer of a randomly-permuted page slab ``[L, P, page,
+    K * D]`` (the other layers hold noise) and the outputs must match
     bit-for-tolerance (page indirection is pure data movement)."""
 
     @staticmethod
-    def _paged_from_contiguous(kc, vc, page, n_pages, seed=0):
+    def _paged_from_contiguous(kc, vc, page, n_pages, seed=0, layers=1,
+                               layer=0):
         B, S, K, D = kc.shape
         M = S // page
         rng = np.random.default_rng(seed)
         perm = rng.permutation(np.arange(1, n_pages))[:B * M]
         table = perm.reshape(B, M).astype(np.int32)
-        k_pages = np.zeros((n_pages, page, K, D), np.float32)
-        v_pages = np.zeros((n_pages, page, K, D), np.float32)
+        shape = (layers, n_pages, page, K * D)
+        k_pages = rng.standard_normal(shape).astype(np.float32)
+        v_pages = rng.standard_normal(shape).astype(np.float32)
         for b in range(B):
             for m in range(M):
-                k_pages[table[b, m]] = np.asarray(kc[b, m * page:(m + 1) * page])
-                v_pages[table[b, m]] = np.asarray(vc[b, m * page:(m + 1) * page])
+                rows = slice(m * page, (m + 1) * page)
+                k_pages[layer, table[b, m]] = np.asarray(
+                    kc[b, rows]).reshape(page, K * D)
+                v_pages[layer, table[b, m]] = np.asarray(
+                    vc[b, rows]).reshape(page, K * D)
         return jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(table)
 
+    @pytest.mark.parametrize("layer", [0, 1])
+    @pytest.mark.parametrize("H,K", [(8, 8), (8, 4), (8, 2)])  # G 1, 2, 4
     @pytest.mark.parametrize("lens", [
         [0, 37, 128], [128, 1, 64], [16, 17, 15],
     ])
-    def test_paged_matches_contiguous(self, lens):
-        B, S, H, K, D = 3, 128, 8, 4, 32
+    def test_paged_matches_contiguous(self, lens, H, K, layer):
+        B, S, D = 3, 128, 32
         page, n_pages = 16, 32
         ks = jax.random.split(KEY, 3)
         q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
         kc = jax.random.normal(ks[1], (B, S, K, D), jnp.float32)
         vc = jax.random.normal(ks[2], (B, S, K, D), jnp.float32)
         lengths = jnp.asarray(lens, jnp.int32)
-        kp, vp, table = self._paged_from_contiguous(kc, vc, page, n_pages)
+        kp, vp, table = self._paged_from_contiguous(
+            kc, vc, page, n_pages, layers=2, layer=layer)
         o_ref = ref.decode_attention(q, kc, vc, lengths)
-        o_pallas = flash_decode_paged(q, kp, vp, table, lengths,
+        o_pallas = flash_decode_paged(q, kp, vp, layer, table, lengths,
                                       interpret=True)
-        o_jnp = ref.paged_decode_attention(q, kp, vp, table, lengths)
+        o_jnp = ref.paged_decode_attention(q, kp, vp, layer, table, lengths)
         np.testing.assert_allclose(np.array(o_pallas), np.array(o_ref),
                                    atol=2e-5, rtol=2e-5)
         np.testing.assert_allclose(np.array(o_jnp), np.array(o_ref),
                                    atol=2e-5, rtol=2e-5)
+        # zero-length rows must be exactly zero, not a uniform V average
+        for b, ln in enumerate(lens):
+            if ln == 0:
+                assert not np.any(np.array(o_pallas[b]))
 
     @pytest.mark.parametrize("page", [16, 128])
     def test_paged_at_serving_widths(self, page):
-        """qwen1.5-0.5b's attention widths (K=16 kv heads, hd=64) at
-        the page sizes the engine serves with."""
+        """qwen1.5-0.5b's attention widths (K=16 kv heads, hd=64, so
+        1024 lanes) at the page sizes the engine serves with."""
         B, S, H, K, D = 2, 256, 16, 16, 64
         ks = jax.random.split(KEY, 3)
         q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
@@ -210,9 +223,9 @@ class TestPagedDecode:
         vc = jax.random.normal(ks[2], (B, S, K, D), jnp.float32)
         lengths = jnp.asarray([S, page + 3], jnp.int32)
         kp, vp, table = self._paged_from_contiguous(
-            kc, vc, page, B * (S // page) + 1)
+            kc, vc, page, B * (S // page) + 1, layers=2, layer=1)
         o_ref = ref.decode_attention(q, kc, vc, lengths)
-        o = flash_decode_paged(q, kp, vp, table, lengths, interpret=True)
+        o = flash_decode_paged(q, kp, vp, 1, table, lengths, interpret=True)
         np.testing.assert_allclose(np.array(o), np.array(o_ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -222,8 +235,9 @@ class TestPagedDecode:
         B, S, K, D = 2, 64, 2, 16
         page = 8
         kc = jax.random.normal(KEY, (B, S, K, D), jnp.float32)
-        kp, _, table = self._paged_from_contiguous(kc, kc, page, 24, seed=3)
-        gathered = kp[table].reshape(B, S, K, D)
+        kp, _, table = self._paged_from_contiguous(kc, kc, page, 24, seed=3,
+                                                   layers=2, layer=1)
+        gathered = kp[1, table].reshape(B, S, K, D)
         np.testing.assert_array_equal(np.array(gathered), np.array(kc))
 
 

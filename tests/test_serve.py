@@ -197,10 +197,11 @@ class TestContinuousEngine:
             row = _static_row(m, params, toks, 8, max_len=32)
             assert eng.results[rid]["tokens"] == list(row)
 
-    @pytest.mark.parametrize("page_size", [0, 2048])
+    @pytest.mark.parametrize("page_size", [0, 8193])
     def test_illegal_page_size_rejected(self, model_params, page_size):
         """The page is the decode kernel's block: a size it cannot tile
-        fails at construction, not at the first compile."""
+        fails at construction, not at the first compile (8192 rows of
+        one 128-lane row, K * hd = 64 here, is the largest it takes)."""
         m, params = model_params
         with pytest.raises(ValueError, match="page_size"):
             ContinuousEngine(m, params, page_size=page_size)

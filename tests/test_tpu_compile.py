@@ -1,11 +1,15 @@
-"""Compile the serving path's Pallas kernels for a described TPU v5e.
+"""Compile the serving path's Pallas kernels and steps for a described
+TPU v5e.
 
 Interpret mode (tests/test_kernels.py) checks what the kernels compute,
 not whether the TPU compiler accepts their tiling. These tests compile
 ``flash_decode_paged``, ``flash_decode`` and the ``flash_attention``
 forward at qwen1.5-0.5b's serving widths (16 query and 16 kv heads, head
 dim 64, bfloat16; 8 slots of 2048 positions) for one chip of a described
-``v5e:2x2`` topology, with no chip attached. Nothing runs, so nothing
+``v5e:2x2`` topology, with no chip attached. They also compile
+``ContinuousEngine``'s decode and prefill steps for qwen1.5-0.5b at
+those sizes and check that the page slab stays whole: no temporaries
+and no data move as large as one layer of it. Nothing runs, so nothing
 about results or speed is checked here.
 
 The topology is described inside a fixture, never at import: only one
@@ -13,18 +17,27 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import math
 import os
+import re
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.decode_attention import (MAX_BLOCK_ROWS, check_page_size,
+from repro.configs.qwen1_5_0_5b import CONFIG as QWEN
+from repro.kernels import ops
+from repro.kernels.decode_attention import (MAX_BLOCK_ELEMS, check_page_size,
                                             flash_decode, flash_decode_paged)
 from repro.kernels.flash_attention import flash_attention
+from repro.models.model import build_model
+from repro.serve.engine import make_decode_step, make_prefill_step
 
-SLOTS, H, K, HD, MAX_LEN = 8, 16, 16, 64, 2048
+SLOTS, H, K, HD, MAX_LEN, PAGE, CHUNK = 8, 16, 16, 64, 2048, 16, 128
+#: Seconds one compile may take; each runs about 1-5 s on a CPU core.
+COMPILE_S = 180
 
 
 @pytest.fixture(scope="module")
@@ -51,22 +64,36 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
+def _lower_and_compile(fn, *args, **jit_kw):
+    """``jax.jit(fn).lower(*args).compile()``, failing past COMPILE_S
+    (the compile itself cannot be stopped: it is left to finish)."""
+    pool = ThreadPoolExecutor(1)
+    try:
+        job = pool.submit(
+            lambda: jax.jit(fn, **jit_kw).lower(*args).compile())
+        return job.result(timeout=COMPILE_S)
+    finally:
+        pool.shutdown(wait=False)
+
+
 def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
             for s, dt in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    text = _lower_and_compile(fn, *args).as_text()
     assert "tpu_custom_call" in text
+
+
+def _paged_shapes(heads, kv_heads, hd, page, M, layers=2):
+    P = SLOTS * M + 1
+    slab = ((layers, P, page, kv_heads * hd), jnp.bfloat16)
+    return (((SLOTS, heads, hd), jnp.bfloat16), slab, slab,
+            ((), jnp.int32), ((SLOTS, M), jnp.int32), ((SLOTS,), jnp.int32))
 
 
 @pytest.mark.parametrize("page", [16, 128])
 def test_flash_decode_paged_compiles(one_chip, page):
-    M = MAX_LEN // page
-    P = SLOTS * M + 1
     _compile(flash_decode_paged, one_chip,
-             ((SLOTS, H, HD), jnp.bfloat16),
-             ((P, page, K, HD), jnp.bfloat16),
-             ((P, page, K, HD), jnp.bfloat16),
-             ((SLOTS, M), jnp.int32), ((SLOTS,), jnp.int32))
+             *_paged_shapes(H, K, HD, page, MAX_LEN // page))
 
 
 @pytest.mark.parametrize("heads,kv_heads,hd", [
@@ -77,17 +104,77 @@ def test_flash_decode_paged_compiles(one_chip, page):
 def test_flash_decode_paged_compiles_at_page_limit(one_chip, heads, kv_heads,
                                                    hd):
     """The largest page ``check_page_size`` admits fits VMEM."""
-    g = heads // kv_heads
-    page = MAX_BLOCK_ROWS // (g * (-(-kv_heads // 8) * 8))
-    check_page_size(page, heads, kv_heads)
+    page = MAX_BLOCK_ELEMS // (-(-kv_heads * hd // 128) * 128)
+    check_page_size(page, kv_heads, hd)
     with pytest.raises(ValueError, match="page_size"):
-        check_page_size(page + 1, heads, kv_heads)
-    M, P = 2, 2 * SLOTS + 1
+        check_page_size(page + 1, kv_heads, hd)
     _compile(flash_decode_paged, one_chip,
-             ((SLOTS, heads, hd), jnp.bfloat16),
-             ((P, page, kv_heads, hd), jnp.bfloat16),
-             ((P, page, kv_heads, hd), jnp.bfloat16),
-             ((SLOTS, M), jnp.int32), ((SLOTS,), jnp.int32))
+             *_paged_shapes(heads, kv_heads, hd, page, 2))
+
+
+_HLO_OP = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+_MOVES = ("copy", "copy-start", "transpose", "dynamic-slice",
+          "dynamic-update-slice")
+
+
+def _large_moves(hlo: str, limit: int) -> list:
+    """Data moves of the optimized HLO whose output holds ``limit``
+    bytes or more: a move by opcode, or a fusion named after one."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m is None:
+            continue
+        name, dtype, dims, opcode = m.groups()
+        if not (opcode in _MOVES or (opcode == "fusion"
+                                     and any(w in name for w in _MOVES))):
+            continue
+        bits = 8 if dtype == "pred" else int(
+            re.match(r"[a-z]+(\d+)", dtype).group(1))
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        if n * bits // 8 >= limit:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_engine_step_keeps_the_slab_whole(one_chip, monkeypatch, step):
+    """The engine's steps carry the donated slab through the layer loop
+    and write it in place: no temporaries and no copy, transpose or
+    slice as large as one layer of it. A slab passed through the scan as
+    xs, or one whose minor dim is a head of 64, makes XLA slice or relay
+    out each layer's part of it on every step."""
+    monkeypatch.setattr(ops, "pallas_mode", lambda: "tpu")
+    model = build_model(QWEN)
+    M = MAX_LEN // PAGE
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = on_chip(model.abstract_params())
+    pages = on_chip(jax.eval_shape(
+        lambda: model.init_paged_cache(SLOTS * M + 1, PAGE)))
+    if step == "decode":
+        fn = make_decode_step(model)
+        args = (params, pages, i32(SLOTS), i32(SLOTS, M), i32(SLOTS),
+                jax.ShapeDtypeStruct((SLOTS,), bool, sharding=one_chip))
+    else:
+        fn = make_prefill_step(model)
+        args = (params, pages, i32(1, CHUNK), i32(M), i32(), i32())
+    compiled = _lower_and_compile(fn, *args, donate_argnums=(1,))
+    slab = pages["k_pages"]
+    layer = (math.prod(slab.shape) // QWEN.num_layers
+             * slab.dtype.itemsize)                  # one layer's keys
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * layer, f"{temp} B of temporaries"
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (step == "decode")
+    assert _large_moves(text, layer) == []
 
 
 def test_flash_decode_compiles(one_chip):
