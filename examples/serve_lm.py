@@ -28,8 +28,6 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = smoke_config(args.arch)
-    if cfg.family == "moe":
-        cfg = cfg.replace(capacity_factor=float(cfg.num_experts))
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
 

@@ -35,6 +35,16 @@ and the stored slab share one layout. Per-head scores come from a
 lane-dense multiply and an NT matmul with the 0/1 head indicator
 ``[K, K * hd]``; the same indicator spreads each head's probabilities
 and softmax state back over its lanes.
+
+:func:`latent_decode_paged` serves latent attention (MLA): one slab
+``[L, P, page, C]`` of latent rows, each read as the key of every head
+(all ``C`` lanes) and as its value (the first ``v_dim``). Its time
+follows the live positions, not slots x max_len: one grid step runs a
+loop over the batch's live blocks of ``LATENT_BLOCK`` positions, a
+flattened list of (slot, block) made from the lengths before the call.
+Each block's pages are copied from HBM into a VMEM double buffer by
+async copies started one block ahead, so dead pages cost neither a copy
+nor a grid step.
 """
 
 from __future__ import annotations
@@ -68,8 +78,9 @@ def check_page_size(page_size: int, num_kv_heads: int,
     limit = MAX_BLOCK_ELEMS // lanes
     if not 1 <= page_size <= limit:
         raise ValueError(
-            f"page_size={page_size} is not a legal paged-decode block for "
-            f"{num_kv_heads} kv heads of {head_dim}: use 1..{limit}")
+            f"page_size={page_size} is not a legal block of "
+            f"flash_decode_paged for {num_kv_heads} kv heads of "
+            f"{head_dim}: use 1..{limit}")
 
 
 def _last_block(length, block_k: int):
@@ -288,3 +299,184 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
       page_table.astype(jnp.int32), qg, k_pages, v_pages)
     return jnp.swapaxes(o.reshape(B, G, K, D), 1, 2).reshape(B, H, D)
+
+
+#: Positions of one slot that one step of ``latent_decode_paged``'s loop
+#: attends: ``LATENT_BLOCK // page`` pages, copied into VMEM together.
+LATENT_BLOCK = 512
+
+
+def check_latent_page_size(page_size: int) -> None:
+    """Raise ValueError unless ``latent_decode_paged`` can take this
+    page: a multiple of 16 rows (a bf16 tile, so that each page's copy
+    lands on whole tiles of the block buffer) that divides
+    ``LATENT_BLOCK``."""
+    if not (page_size >= 16 and page_size % 16 == 0
+            and LATENT_BLOCK % page_size == 0):
+        raise ValueError(
+            f"page_size={page_size} is not a legal block of "
+            f"latent_decode_paged: use a multiple of 16 that divides "
+            f"{LATENT_BLOCK}")
+
+
+def _dot_f32(a, b, dims):
+    """``a . b`` with an f32 result: exact products of bf16 operands, all
+    passes (HIGHEST) for f32 ones."""
+    hi = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(a, b, dims, precision=hi,
+                               preferred_element_type=jnp.float32)
+
+
+def _mm_f32(a, b, dims):
+    """``a . b`` in f32 for an f32 ``a`` and ``b`` exact in its own dtype.
+    A bf16 ``b`` takes ``a`` split into two bf16 terms (hi + lo), which
+    keeps ``a`` to about 16 bits where one bf16 pass would round it to
+    8."""
+    dot = functools.partial(_dot_f32, dims=dims)
+    if b.dtype == jnp.float32:
+        return dot(a, b)
+    a_hi = a.astype(b.dtype)
+    a_lo = (a - a_hi.astype(jnp.float32)).astype(b.dtype)
+    return dot(a_hi, b) + dot(a_lo, b)
+
+
+def _latent_kernel(layer_ref, len_ref, tbl_ref, slot_ref, blk_ref, n_ref,
+                   q_ref, slab_ref, o_ref, buf, sem, *, page: int,
+                   v_dim: int, sm_scale: float):
+    T = buf.shape[1]
+    ppb = T // page
+    M = tbl_ref.shape[1]
+    H = q_ref.shape[1]
+    layer = layer_ref[0]
+    n_items = n_ref[0]
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def copies(item, half):
+        """The page copies of work item ``item`` into buffer ``half``,
+        each with whether its page holds a live position."""
+        b = slot_ref[item]
+        out = []
+        for i in range(ppb):
+            j = blk_ref[item] * ppb + i
+            pid = tbl_ref[b, jnp.minimum(j, M - 1)]
+            cp = pltpu.make_async_copy(slab_ref.at[layer, pid],
+                                       buf.at[half, pl.ds(i * page, page)],
+                                       sem.at[half])
+            out.append((j * page < len_ref[b], cp))
+        return out
+
+    def start(item, half):
+        for live, cp in copies(item, half):
+            pl.when(live)(cp.start)
+
+    def wait(item, half):
+        for live, cp in copies(item, half):
+            pl.when(live)(cp.wait)
+
+    @pl.when(n_items > 0)
+    def _():
+        start(0, 0)
+
+    def body(item, carry):
+        m, l, acc = carry
+        half = item % 2
+
+        @pl.when(item + 1 < n_items)
+        def _():
+            start(item + 1, 1 - half)
+
+        wait(item, half)
+        b, blk = slot_ref[item], blk_ref[item]
+        length = len_ref[b]
+        first = blk == 0
+        m = jnp.where(first, NEG_INF, m)
+        l = jnp.where(first, 0.0, l)
+        acc = jnp.where(first, 0.0, acc)
+        rows = buf[half]                                   # [T, C]
+        s = _dot_f32(q_ref[b], rows,
+                     (((1,), (1,)), ((), ()))) * sm_scale    # [H, T]
+        pos = blk * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        s = jnp.where(pos < length, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)                             # [H, T]
+        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # rows past the length hold whatever the buffer held: zero them,
+        # since a zero weight times a stale NaN is still NaN
+        col = blk * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+        v = jnp.where(col < length, rows[:, :v_dim], 0)
+        acc = acc * alpha + _mm_f32(p, v, (((1,), (0,)), ((), ())))
+
+        @pl.when((blk + 1) * T >= length)
+        def _():
+            o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+        return m_new, l, acc
+
+    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, v_dim), jnp.float32))
+    jax.lax.fori_loop(0, n_items, body, init)
+
+
+def _latent_work_items(lengths: jax.Array, n_blocks: int):
+    """The flattened (slot, block) list of a batch: ``(slots, blocks,
+    count)``, each list ``B * n_blocks`` long with the first ``count``
+    entries live, slot by slot, block by block."""
+    B = lengths.shape[0]
+    nb = (lengths + LATENT_BLOCK - 1) // LATENT_BLOCK
+    ends = jnp.cumsum(nb)
+    i = jnp.arange(B * n_blocks, dtype=jnp.int32)
+    # one compare and sum, not a search: a search is a loop of small ops
+    # on the device, paid in every layer
+    slots = jnp.minimum(jnp.sum(ends[None, :] <= i[:, None], axis=1,
+                                dtype=jnp.int32), B - 1)
+    blocks = i - jnp.take(ends - nb, slots)
+    return slots, blocks.astype(jnp.int32), ends[-1:].astype(jnp.int32)
+
+
+def latent_decode_paged(q: jax.Array, kv_pages: jax.Array, layer,
+                        page_table: jax.Array, lengths: jax.Array, *,
+                        sm_scale: float, v_dim: int,
+                        interpret: bool = False) -> jax.Array:
+    """Latent (MLA) decode attention of one layer through a page table.
+
+    q: [B, H, C], the absorbed queries; kv_pages: [L, P, page, C], the
+    whole latent slab (page 0 of every layer the null page); layer:
+    int32 scalar; page_table: [B, M] int32; lengths: [B] -> [B, H,
+    v_dim]. Row ``t`` of sequence ``b`` lives at ``[layer, page_table[b,
+    t // page], t % page]``; it is the key of every head and its first
+    ``v_dim`` lanes the value. Scores ``q . row * sm_scale`` in f32 (one
+    MXU product for all heads of a block), f32 online softmax; rows with
+    ``lengths == 0`` emit zeros. Only live pages are copied.
+    """
+    B, H, C = q.shape
+    page = kv_pages.shape[2]
+    check_latent_page_size(page)
+    M = page_table.shape[1]
+    n_blocks = -(-M * page // LATENT_BLOCK)
+    lengths = lengths.astype(jnp.int32)
+    slots, blocks, count = _latent_work_items(lengths, n_blocks)
+
+    def whole(*_):
+        return (0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(1,),
+        in_specs=[pl.BlockSpec((B, H, C), whole),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((B, H, v_dim), whole),
+        scratch_shapes=[pltpu.VMEM((2, LATENT_BLOCK, C), kv_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    kernel = functools.partial(_latent_kernel, page=page, v_dim=v_dim,
+                               sm_scale=sm_scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, v_dim), q.dtype),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="latent_decode_paged",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths,
+      page_table.astype(jnp.int32), slots, blocks, count, q, kv_pages)

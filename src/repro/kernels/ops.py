@@ -18,14 +18,17 @@ from typing import Optional
 import jax
 
 from . import ref
-from .decode_attention import (check_page_size, flash_decode,
-                               flash_decode_paged)
+from .decode_attention import (check_latent_page_size, check_page_size,
+                               flash_decode, flash_decode_paged,
+                               latent_decode_paged)
 from .flash_attention import flash_attention
 from .mamba2_scan import mamba2_chunked
 from .rwkv6_scan import rwkv6_chunked
 
 __all__ = ["attention", "decode_attention", "paged_decode_attention",
-           "check_page_size", "rwkv6_scan", "mamba2_scan", "pallas_mode"]
+           "latent_decode_attention", "check_page_size",
+           "check_latent_page_size", "rwkv6_scan", "mamba2_scan",
+           "pallas_mode"]
 
 
 @functools.lru_cache(None)
@@ -73,6 +76,21 @@ def paged_decode_attention(q, k_pages, v_pages, layer, page_table, lengths,
                                   lengths, sm_scale=sm_scale)
     return ref.paged_decode_attention(q, k_pages, v_pages, layer,
                                       page_table, lengths, sm_scale=sm_scale)
+
+
+def latent_decode_attention(q, kv_pages, layer, page_table, lengths, *,
+                            sm_scale: float, v_dim: int):
+    """Latent (MLA) decode of one layer through a page table, reading the
+    whole latent slab ``[L, P, page, C]``: each row is every head's key
+    and, in its first ``v_dim`` lanes, value. See
+    ref.latent_decode_attention for semantics. The Pallas path copies only
+    the slots' live pages (``latent_decode_paged``)."""
+    if pallas_mode() == "tpu":
+        return latent_decode_paged(q, kv_pages, layer, page_table, lengths,
+                                   sm_scale=sm_scale, v_dim=v_dim)
+    return ref.latent_decode_attention(q, kv_pages, layer, page_table,
+                                       lengths, sm_scale=sm_scale,
+                                       v_dim=v_dim)
 
 
 def rwkv6_scan(r, k, v, w, u, state=None):

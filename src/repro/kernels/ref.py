@@ -195,6 +195,33 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     return decode_attention(q, kc, vc, lengths, sm_scale=sm_scale)
 
 
+def latent_decode_attention(q: jax.Array, kv_pages: jax.Array, layer,
+                            page_table: jax.Array, lengths: jax.Array, *,
+                            sm_scale: float, v_dim: int) -> jax.Array:
+    """Latent (MLA) decode attention of one layer through the paged latent
+    slab (the oracle).
+
+    q: [B, H, C] absorbed queries; kv_pages: [L, P, page_size, C] — the
+    whole slab of latent rows; page_table: [B, M]; lengths: [B]. Row
+    ``t`` of sequence ``b`` lives at ``[layer, page_table[b, t //
+    page_size], t % page_size]`` and is every head's key (all ``C``
+    lanes) and value (the first ``v_dim``). f32 softmax of ``q . row *
+    sm_scale`` over the first ``lengths[b]`` rows; a length-0 row gives
+    zeros. Returns [B, H, v_dim] in q.dtype.
+    """
+    B, H, C = q.shape
+    page_size = kv_pages.shape[2]
+    S = page_table.shape[1] * page_size
+    rows = kv_pages[layer, page_table].reshape(B, S, C).astype(jnp.float32)
+    s = jnp.einsum("bhc,bsc->bhs", q.astype(jnp.float32), rows) * sm_scale
+    valid = jnp.arange(S)[None, :] < lengths[:, None]
+    s = jnp.where(valid[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhs,bsr->bhr", p, rows[..., :v_dim])
+    o = jnp.where((lengths > 0)[:, None, None], o, 0.0)
+    return o.astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Chunked time scan (bounded-memory BPTT for the recurrences)
 # ---------------------------------------------------------------------------

@@ -31,6 +31,22 @@ class ModelConfig:
     num_shared_experts: int = 0             # kimi-k2 style shared expert
     capacity_factor: float = 1.25
     moe_impl: str = "dense"                 # "dense" (GShard einsum) | "gather"
+    router_score: str = "softmax"           # softmax | sigmoid (deepseek-v3:
+                                            # a bias added for the choice only)
+    routed_scale: float = 1.0               # deepseek-v3 routed_scaling_factor
+    first_dense_layers: int = 0             # leading layers with a d_ff MLP
+    # the chip's expert share: experts [expert_offset, expert_offset +
+    # experts_held) of the router's num_experts live here (0: all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
+
+    # latent attention (MLA, deepseek-v3), on when kv_lora_rank > 0: keys
+    # and values come from one kv_lora_rank-wide latent plus one rope key
+    # shared by all heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # SSM (rwkv6 / mamba2)
     ssm_state: int = 0
@@ -72,6 +88,15 @@ class ModelConfig:
         return self.head_dim if self.head_dim is not None else self.d_model // self.num_heads
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts this chip holds."""
+        return self.expert_offset, self.experts_held or self.num_experts
+
+    @property
     def d_inner(self) -> int:               # mamba2
         return self.ssm_expand * self.d_model
 
@@ -103,6 +128,11 @@ class ModelConfig:
         H, K, hd = self.num_heads, self.num_kv_heads, self.hd
 
         def attn_params() -> int:
+            if self.is_mla:
+                R, rp = self.kv_lora_rank, self.qk_rope_head_dim
+                nope, v = self.qk_nope_head_dim, self.v_head_dim
+                return (D * H * (nope + rp) + D * (R + rp) + R
+                        + R * H * (nope + v) + H * v * D)
             p = D * H * hd + 2 * D * K * hd + H * hd * D
             if self.qkv_bias:
                 p += H * hd + 2 * K * hd
@@ -123,11 +153,15 @@ class ModelConfig:
             e_all = self.num_experts * 3 * D * self.moe_d_ff
             e_act = (self.experts_per_token + self.num_shared_experts) * 3 * D * self.moe_d_ff
             router = D * self.num_experts
+            if self.router_score == "sigmoid":
+                router += self.num_experts          # the choice bias
             shared = self.num_shared_experts * 3 * D * self.moe_d_ff
             per_layer_total = attn_params() + e_all + shared + router + 2 * D
             per_layer_active = attn_params() + e_act + router + 2 * D
-            total += L * per_layer_total
-            active += L * per_layer_active
+            n_dense = self.first_dense_layers
+            dense = attn_params() + mlp_params(F) + 2 * D
+            total += (L - n_dense) * per_layer_total + n_dense * dense
+            active += (L - n_dense) * per_layer_active + n_dense * dense
         elif self.family == "ssm":  # rwkv6
             Hh, hdh = self.ssm_heads, self.ssm_head_dim
             tm = 5 * D * D + D * D + 2 * 64 * D + Hh * hdh + 5 * D  # r,k,v,g,o + decay lora + u + mus

@@ -277,6 +277,179 @@ def apply_attention_prefill_paged(p: Params, cfg: ModelConfig, x: jax.Array,
     return out, k_pages, v_pages
 
 
+# ---------------------------------------------------------------------------
+# Latent attention (MLA, deepseek-v3)
+# ---------------------------------------------------------------------------
+#
+# Per token, ``wq`` gives each head ``q_nope`` (qk_nope_head_dim) and
+# ``q_pe`` (qk_rope_head_dim); ``wkv_a`` gives one latent ``c``
+# (kv_lora_rank, then ``kv_norm``) and one rope key ``k_pe`` shared by
+# all heads; ``wkv_b`` would expand ``c`` into each head's ``k_nope`` and
+# value. Scores are ``(q_nope . k_nope + q_pe . k_pe) * (nope + rope) **
+# -0.5``. Rope acts on ``q_pe`` and ``k_pe`` after DeepSeek's pair
+# interleave (even lanes first, then odd), then rotates halves.
+#
+# Served in the absorbed form: ``W_UK`` (the key half of ``wkv_b``) goes
+# into the query, ``q_lat = q_nope @ W_UK^T``, so a head's query is
+# ``[q_lat, q_pe]`` against the cached row ``[c, k_pe]`` (576 lanes at
+# Moonlight's widths); the value is ``c`` itself, and ``W_UV`` (the value
+# half) takes the head's output out of the latent. The cache holds that
+# one row per token, and prefill and decode read it alike. The slab's
+# rows are zero-padded to whole 128-lane tiles (640 lanes for 576): XLA
+# stores a 576-lane minor dim in 640 anyway, and a kernel's copy of a row
+# can only take whole tiles; the queries get the same zero lanes.
+
+
+def init_mla(key, cfg: ModelConfig) -> Params:
+    D, H = cfg.d_model, cfg.num_heads
+    R, rp = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    dt = cfg.p_dtype()
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], D, H * (nope + rp), dt),
+        "wkv_a": dense_init(ks[1], D, R + rp, dt),
+        "kv_norm": _norm_init(R, dt),
+        "wkv_b": dense_init(ks[2], R, H * (nope + vd), dt),
+        "wo": dense_init(ks[3], H * vd, D, dt, scale=(H * vd) ** -0.5),
+    }
+
+
+def _interleaved_rope(x: jax.Array, positions: jax.Array,
+                      theta: float) -> jax.Array:
+    """DeepSeek's rope: lanes ``(x0, x1, x2, ...)`` regrouped as ``(x0,
+    x2, ..., x1, x3, ...)``, then the half rotation of :func:`rope`.
+    x: [..., S, H, D]; positions: [..., S]."""
+    D = x.shape[-1]
+    x = jnp.swapaxes(x.reshape(*x.shape[:-1], D // 2, 2), -1, -2)
+    return rope(x.reshape(*x.shape[:-2], D), positions, theta)
+
+
+def latent_lanes(cfg: ModelConfig) -> int:
+    """Lanes of a latent slab row: the latent and the rope key, rounded
+    up to whole 128-lane tiles."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _pad_lanes(x: jax.Array, n: int) -> jax.Array:
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, n - x.shape[-1])])
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def _mla_wkv_b(p: Params, cfg: ModelConfig):
+    """``(W_UK [R, H, nope], W_UV [R, H, v])``, the halves of wkv_b."""
+    H, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    w = p["wkv_b"].reshape(cfg.kv_lora_rank, H, -1)
+    return w[..., :nope], w[..., nope:]
+
+
+def mla_project(p: Params, cfg: ModelConfig, x: jax.Array,
+                positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """x: [B, S, D] -> (queries [B, S, H, R + rope], rows [B, S, R +
+    rope]): the absorbed queries and the latent rows to cache."""
+    B, S, _ = x.shape
+    H, R = cfg.num_heads, cfg.kv_lora_rank
+    nope = cfg.qk_nope_head_dim
+    q = jnp.einsum("bsd,df->bsf", x, p["wq"]).reshape(B, S, H, -1)
+    q_pe = _interleaved_rope(q[..., nope:], positions, cfg.rope_theta)
+    w_uk, _ = _mla_wkv_b(p, cfg)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope], w_uk)
+    kv = jnp.einsum("bsd,df->bsf", x, p["wkv_a"])
+    c = rms_norm(kv[..., :R], p["kv_norm"], cfg.norm_eps)
+    k_pe = _interleaved_rope(kv[..., None, R:], positions,
+                             cfg.rope_theta)[..., 0, :]
+    return (jnp.concatenate([q_lat, q_pe], axis=-1),
+            jnp.concatenate([c, k_pe], axis=-1))
+
+
+def mla_out(p: Params, cfg: ModelConfig, o_lat: jax.Array) -> jax.Array:
+    """Heads' outputs in the latent, [B, S, H, R] -> [B, S, D]."""
+    B, S = o_lat.shape[:2]
+    _, w_uv = _mla_wkv_b(p, cfg)
+    o = jnp.einsum("bshr,rhv->bshv", o_lat, w_uv)
+    return jnp.einsum("bsf,fd->bsd", o.reshape(B, S, -1), p["wo"])
+
+
+def latent_attention(q: jax.Array, rows: jax.Array, mask: jax.Array,
+                     scale: float, v_dim: int) -> jax.Array:
+    """Softmax attention of absorbed queries over latent rows, in f32.
+    q: [B, S, H, C]; rows: [B, T, C]; mask: [S, T] -> [B, S, H, v_dim]
+    (the value of a row is its first ``v_dim`` lanes)."""
+    rf = rows.astype(jnp.float32)
+    s = jnp.einsum("bqhc,btc->bhqt", q.astype(jnp.float32), rf) * scale
+    s = jnp.where(mask[None, None], s, -1e30)
+    probs = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqt,btr->bqhr", probs, rf[..., :v_dim])
+    return o.astype(q.dtype)
+
+
+def apply_mla(p: Params, cfg: ModelConfig, x: jax.Array,
+              positions: jax.Array) -> jax.Array:
+    """Full-sequence causal latent attention (training, reference tests)."""
+    S = x.shape[1]
+    q, rows = mla_project(p, cfg, x, positions)
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    o = latent_attention(q, rows, causal, mla_scale(cfg), cfg.kv_lora_rank)
+    return mla_out(p, cfg, o)
+
+
+def apply_mla_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
+                           kv_pages: jax.Array, layer,
+                           page_table: jax.Array, lengths: jax.Array,
+                           slot_mask: jax.Array
+                           ) -> Tuple[jax.Array, jax.Array]:
+    """One-token latent decode of layer ``layer`` against the latent slab.
+
+    x: [B, 1, D]; kv_pages: [L, P, page, lanes], the whole slab, page
+    0 the null page; the rest as in :func:`apply_attention_decode_paged`:
+    the new row is written at ``lengths`` (a masked slot's to the null
+    page) by one scatter into the slab as passed in, then
+    ``ops.latent_decode_attention`` reads each slot's live pages once,
+    as keys (all lanes) and values (the latent's), and ``W_UV`` and
+    ``wo`` take the heads' outputs out of the latent."""
+    B = x.shape[0]
+    page, lanes = kv_pages.shape[2:]
+    q, row = mla_project(p, cfg, x, lengths[:, None])
+    q, row = _pad_lanes(q, lanes), _pad_lanes(row, lanes)
+    pid = page_table[jnp.arange(B), lengths // page]
+    pid = jnp.where(slot_mask, pid, 0)
+    kv_pages = kv_pages.at[layer, pid, lengths % page].set(
+        row[:, 0].astype(kv_pages.dtype))
+    att_len = jnp.where(slot_mask, lengths + 1, 0)
+    o = ops.latent_decode_attention(q[:, 0], kv_pages, layer, page_table,
+                                    att_len, sm_scale=mla_scale(cfg),
+                                    v_dim=cfg.kv_lora_rank)
+    return mla_out(p, cfg, o[:, None]), kv_pages
+
+
+def apply_mla_prefill_paged(p: Params, cfg: ModelConfig, x: jax.Array,
+                            kv_pages: jax.Array, layer,
+                            page_table: jax.Array, start: jax.Array,
+                            n_valid: jax.Array
+                            ) -> Tuple[jax.Array, jax.Array]:
+    """Chunked latent prefill of layer ``layer`` for ONE request, as
+    :func:`apply_attention_prefill_paged` does it: the chunk's rows are
+    scattered into the slab first (padding rows to the null page), then
+    its absorbed queries attend the request's whole window ``[M * page]``
+    under ``col <= start + row``, f32 softmax."""
+    _, C, _ = x.shape
+    page, lanes = kv_pages.shape[2:]
+    M = page_table.shape[0]
+    tpos = start + jnp.arange(C, dtype=jnp.int32)
+    q, rows = mla_project(p, cfg, x, tpos[None])
+    q, rows = _pad_lanes(q, lanes), _pad_lanes(rows, lanes)
+    pid = jnp.where(jnp.arange(C) < n_valid, page_table[tpos // page], 0)
+    kv_pages = kv_pages.at[layer, pid, tpos % page].set(
+        rows[0].astype(kv_pages.dtype))
+    window = kv_pages[layer, page_table].reshape(1, M * page, -1)
+    causal = jnp.arange(M * page, dtype=jnp.int32)[None, :] <= tpos[:, None]
+    o = latent_attention(q, window, causal, mla_scale(cfg), cfg.kv_lora_rank)
+    return mla_out(p, cfg, o), kv_pages
+
+
 def init_cross_attention(key, cfg: ModelConfig) -> Params:
     return init_attention(key, cfg)
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
+from ..kernels import ops
 from ..sharding.ctx import constrain
 from . import layers as L
 from . import mamba as M
@@ -103,14 +104,21 @@ class Model:
             params["layers"] = _stack_init(
                 k_layers, cfg.num_layers, lambda k: L.init_dense_block(k, cfg))
         elif fam == "moe":
-            def init_moe_block(k):
+            init_attn = L.init_mla if cfg.is_mla else L.init_attention
+
+            def init_block(k, ffn_key, init_ffn):
                 k1, k2 = jax.random.split(k)
-                blk = {"attn": L.init_attention(k1, cfg),
-                       "moe": X.init_moe(k2, cfg),
-                       "norm1": jnp.ones((cfg.d_model,), cfg.p_dtype()),
-                       "norm2": jnp.ones((cfg.d_model,), cfg.p_dtype())}
-                return blk
-            params["layers"] = _stack_init(k_layers, cfg.num_layers, init_moe_block)
+                return {"attn": init_attn(k1, cfg), ffn_key: init_ffn(k2),
+                        "norm1": jnp.ones((cfg.d_model,), cfg.p_dtype()),
+                        "norm2": jnp.ones((cfg.d_model,), cfg.p_dtype())}
+            n_dense = cfg.first_dense_layers
+            if n_dense:
+                params["dense_layers"] = _stack_init(
+                    k_extra, n_dense, lambda k: init_block(
+                        k, "mlp", lambda k2: L.init_mlp(k2, cfg)))
+            params["layers"] = _stack_init(
+                k_layers, cfg.num_layers - n_dense, lambda k: init_block(
+                    k, "moe", lambda k2: X.init_moe(k2, cfg)))
         elif fam == "ssm":
             params["layers"] = _stack_init(
                 k_layers, cfg.num_layers, lambda k: R.init_rwkv_block(k, cfg))
@@ -157,6 +165,12 @@ class Model:
                 return L.apply_dense_block(layer, cfg, h, positions), None
             body = self._maybe_remat(body)
             x, _ = scan_over(cfg, body, x, params["layers"])
+            return x, jnp.zeros((), jnp.float32)
+
+        if cfg.is_mla:
+            def attend(p, h, pages, i):
+                return L.apply_mla(p, cfg, h, positions), pages
+            x, _, _ = self._layers(params, x, {}, attend)
             return x, jnp.zeros((), jnp.float32)
 
         if fam == "moe":
@@ -292,10 +306,19 @@ class Model:
 
     # ------------------------------------------------------------- serving
 
+    def _check_contiguous(self):
+        if self.cfg.is_mla:
+            raise ValueError(
+                f"{self.cfg.name}: latent attention (MLA) is served from the "
+                f"latent page slab only (init_paged_cache, "
+                f"prefill_paged_chunk, decode_paged); the contiguous "
+                f"cache holds keys and values per kv head")
+
     def init_cache(self, batch_size: int, max_len: int,
                    enc_len: int = 0) -> Dict[str, Any]:
         """Abstract/zeroed cache pytree for decode."""
         cfg = self.cfg
+        self._check_contiguous()
         dt = cfg.act_dtype()
         B, Lc = batch_size, cfg.num_layers
         K, hd = cfg.num_kv_heads, cfg.hd
@@ -331,6 +354,7 @@ class Model:
                 max_len: int) -> Tuple[jax.Array, Dict[str, Any]]:
         """Process a full prompt; returns (last-position logits, cache)."""
         cfg = self.cfg
+        self._check_contiguous()
         fam = cfg.family
         if fam == "encdec":
             return self._prefill_encdec(params, batch, max_len)
@@ -354,7 +378,7 @@ class Model:
                         layer["attn"], cfg,
                         L.rms_norm(h, layer["norm1"], cfg.norm_eps), pos)
                     h = h + a[0]
-                    mo, _ = X.apply_moe(
+                    mo, _ = X.apply_moe_held(
                         layer["moe"], cfg,
                         L.rms_norm(h, layer["norm2"], cfg.norm_eps))
                     h = h + mo
@@ -437,71 +461,117 @@ class Model:
                 f"paged serving requires a KV-cache family, "
                 f"got {self.cfg.family!r}")
 
+    def check_page_size(self, page_size: int) -> None:
+        """Raise ValueError unless the paged decode kernel this model
+        runs (``latent_decode_paged`` for latent attention, else
+        ``flash_decode_paged``) can take pages of ``page_size``."""
+        if self.cfg.is_mla:
+            ops.check_latent_page_size(page_size)
+        else:
+            ops.check_page_size(page_size, self.cfg.num_kv_heads,
+                                self.cfg.hd)
+
     def init_paged_cache(self, num_pages: int, page_size: int
                          ) -> Dict[str, jax.Array]:
-        """Zeroed page slab: {'k_pages','v_pages': [L, P, page, K * hd]},
-        the kv heads side by side on the lane-dense minor dim."""
+        """Zeroed page slab. GQA/MHA: {'k_pages','v_pages': [L, P, page,
+        K * hd]}, the kv heads side by side on the lane-dense minor dim.
+        Latent attention: {'kv_pages': [L, P, page, lanes]}, one latent
+        row per token (``kv_lora_rank`` latent lanes, then the rope key,
+        zero-padded to whole 128-lane tiles)."""
         self._check_paged()
         cfg = self.cfg
+        dt = cfg.act_dtype()
+        if cfg.is_mla:
+            return {"kv_pages": jnp.zeros(
+                (cfg.num_layers, num_pages, page_size, L.latent_lanes(cfg)),
+                dt)}
         shape = (cfg.num_layers, num_pages, page_size,
                  cfg.num_kv_heads * cfg.hd)
-        dt = cfg.act_dtype()
         return {"k_pages": jnp.zeros(shape, dt),
                 "v_pages": jnp.zeros(shape, dt)}
 
-    def _paged_layers(self, params: Params, x: jax.Array,
-                      pages: Dict[str, jax.Array], attend
-                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """Run the stacked layers over the slab. ``attend(attn_params, h,
-        k_pages, v_pages, layer) -> (a, k_pages, v_pages)`` is the
-        layer's attention against the whole slab."""
+    def _layers(self, params: Params, x: jax.Array, pages: Dict, attend,
+                live: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, Dict, jax.Array]:
+        """Run the stacked layers, the slab carried whole: first the
+        leading dense layers (``params['dense_layers']``, if any), then
+        ``params['layers']``, the layer index running on across both.
+        ``attend(attn_params, h, pages, layer) -> (a, pages)`` is a
+        layer's attention, against the slab on the paged entry points
+        (the latent model's full-sequence forward passes no slab and
+        attends the sequence itself). MoE layers run the served,
+        dropless routed layer; ``live`` ([B, S] bool) marks the tokens
+        whose (token, held expert) assignments are counted. Returns
+        ``(x, pages, held)``, ``held`` that count over the layers."""
         cfg = self.cfg
         # the moe block has no residual scale, as in ``_backbone``
         r = 1.0 if cfg.family == "moe" else cfg.residual_scale
 
-        def body(carry, xs):
-            h, kp, vp = carry
-            layer, i = xs
-            a, kp, vp = attend(layer["attn"], L.rms_norm(
-                h, layer["norm1"], cfg.norm_eps), kp, vp, i)
-            h = h + r * a
-            hn = L.rms_norm(h, layer["norm2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                mo, _ = X.apply_moe(layer["moe"], cfg, hn)
-            else:
-                mo = L.apply_mlp(layer["mlp"], hn)
-            return (h + r * mo, kp, vp), None
+        def mlp(layer, hn):
+            return L.apply_mlp(layer["mlp"], hn), 0
 
-        idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-        (x, kp, vp), _ = scan_over(
-            cfg, body, (x, pages["k_pages"], pages["v_pages"]),
-            (params["layers"], idx))
-        return x, {"k_pages": kp, "v_pages": vp}
+        def moe(layer, hn):
+            return X.apply_moe_held(layer["moe"], cfg, hn, live)
+
+        def run(carry, layers, first, ffn):
+            def body(carry, xs):
+                h, pg, held = carry
+                layer, i = xs
+                a, pg = attend(layer["attn"], L.rms_norm(
+                    h, layer["norm1"], cfg.norm_eps), pg, i)
+                h = h + r * a
+                mo, n = ffn(layer, L.rms_norm(h, layer["norm2"],
+                                              cfg.norm_eps))
+                return (h + r * mo, pg, held + n), None
+
+            n_layers = jax.tree.leaves(layers)[0].shape[0]
+            idx = first + jnp.arange(n_layers, dtype=jnp.int32)
+            carry, _ = scan_over(cfg, body, carry, (layers, idx))
+            return carry
+
+        carry = (x, pages, jnp.zeros((), jnp.int32))
+        n_dense = 0
+        if "dense_layers" in params:
+            carry = run(carry, params["dense_layers"], 0, mlp)
+            n_dense = cfg.first_dense_layers
+        return run(carry, params["layers"], n_dense,
+                   moe if cfg.family == "moe" else mlp)
 
     def decode_paged(self, params: Params, pages: Dict[str, jax.Array],
                      tokens: jax.Array, page_tables: jax.Array,
-                     lengths: jax.Array, slot_mask: jax.Array
-                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                     lengths: jax.Array, slot_mask: jax.Array):
         """One decode step over the page slab.
 
         tokens: [B] int32; page_tables: [B, M] int32; lengths: [B]
         (cache entries already written; the new token lands at position
         ``lengths``); slot_mask: [B] bool — idle slots write to the null
         page and produce garbage logits the engine ignores.
-        Returns ([B, V] logits, new pages).
+        Returns ([B, V] logits, new pages, held): ``held`` the live
+        slots' (token, held expert) assignments over the layers, a
+        constant 0 for a model without routed experts.
         """
         self._check_paged()
         cfg = self.cfg
         x = L.embed(params["embed"], cfg, tokens[:, None])
 
-        def attend(p, h, kp, vp, i):
-            return L.apply_attention_decode_paged(
-                p, cfg, h, kp, vp, i, page_tables, lengths, slot_mask)
+        if cfg.is_mla:
+            def attend(p, h, pages, i):
+                a, kv = L.apply_mla_decode_paged(
+                    p, cfg, h, pages["kv_pages"], i, page_tables, lengths,
+                    slot_mask)
+                return a, {"kv_pages": kv}
+        else:
+            def attend(p, h, pages, i):
+                a, kp, vp = L.apply_attention_decode_paged(
+                    p, cfg, h, pages["k_pages"], pages["v_pages"], i,
+                    page_tables, lengths, slot_mask)
+                return a, {"k_pages": kp, "v_pages": vp}
 
-        x, pages = self._paged_layers(params, x, pages, attend)
+        x, pages, held = self._layers(params, x, pages, attend,
+                                      live=slot_mask[:, None])
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = L.unembed(params["embed"], cfg, x)[:, 0]
-        return logits, pages
+        return logits, pages, held
 
     def prefill_paged_chunk(self, params: Params,
                             pages: Dict[str, jax.Array],
@@ -520,11 +590,20 @@ class Model:
         cfg = self.cfg
         x = L.embed(params["embed"], cfg, tokens)
 
-        def attend(p, h, kp, vp, i):
-            return L.apply_attention_prefill_paged(
-                p, cfg, h, kp, vp, i, page_table, start, n_valid)
+        if cfg.is_mla:
+            def attend(p, h, pages, i):
+                a, kv = L.apply_mla_prefill_paged(
+                    p, cfg, h, pages["kv_pages"], i, page_table, start,
+                    n_valid)
+                return a, {"kv_pages": kv}
+        else:
+            def attend(p, h, pages, i):
+                a, kp, vp = L.apply_attention_prefill_paged(
+                    p, cfg, h, pages["k_pages"], pages["v_pages"], i,
+                    page_table, start, n_valid)
+                return a, {"k_pages": kp, "v_pages": vp}
 
-        x, pages = self._paged_layers(params, x, pages, attend)
+        x, pages, _ = self._layers(params, x, pages, attend)
         last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         last = L.rms_norm(last, params["final_norm"], cfg.norm_eps)
         logits = L.unembed(params["embed"], cfg, last)[:, 0]
@@ -534,6 +613,7 @@ class Model:
                tokens: jax.Array) -> Tuple[jax.Array, Dict[str, Any]]:
         """One decode step. tokens: [B] int32. Returns ([B, V] logits, cache)."""
         cfg = self.cfg
+        self._check_contiguous()
         fam = cfg.family
         B = tokens.shape[0]
         lengths = cache["lengths"]
@@ -548,7 +628,7 @@ class Model:
                         L.rms_norm(h, layer["norm1"], cfg.norm_eps),
                         ck, cv, lengths)
                     h = h + a
-                    mo, _ = X.apply_moe(
+                    mo, _ = X.apply_moe_held(
                         layer["moe"], cfg,
                         L.rms_norm(h, layer["norm2"], cfg.norm_eps))
                     h = h + mo

@@ -15,7 +15,16 @@ Two interchangeable implementations (cfg.moe_impl):
              local experts' contributions for all tokens, then psums.
 
 Both apply top-k routing with softmax-renormalized gates and optional
-shared experts (kimi-k2) that every token visits.
+shared experts (kimi-k2) that every token visits. They serve training,
+where a token over an expert's capacity may be dropped.
+
+``apply_moe_held`` is the routed layer as served (``Model.prefill`` /
+``decode`` and the paged entry points): it drops nothing, so a token's
+output does not depend on how its prompt was chunked or on what the
+other slots hold, and it computes only the part of the experts that
+this chip holds (``ModelConfig.held_experts``), routing over all of
+them. On one chip it runs without the exchange; the partial result goes
+on to the next layer.
 """
 
 from __future__ import annotations
@@ -32,16 +41,20 @@ Params = Dict[str, Any]
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
-    D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    """Router over all ``num_experts``; expert weights of the held ones."""
+    D, F = cfg.d_model, cfg.moe_d_ff
+    E = cfg.held_experts[1]
     dt = cfg.p_dtype()
     ks = jax.random.split(key, 5)
     std = D ** -0.5
     p = {
-        "router": dense_init(ks[0], D, E, jnp.float32),  # router in f32
+        "router": dense_init(ks[0], D, cfg.num_experts, jnp.float32),
         "wi": (jax.random.normal(ks[1], (E, D, F), jnp.float32) * std).astype(dt),
         "wg": (jax.random.normal(ks[2], (E, D, F), jnp.float32) * std).astype(dt),
         "wo": (jax.random.normal(ks[3], (E, F, D), jnp.float32) * F ** -0.5).astype(dt),
     }
+    if cfg.router_score == "sigmoid":
+        p["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
     if cfg.num_shared_experts:
         Fs = F * cfg.num_shared_experts
         kss = jax.random.split(ks[4], 3)
@@ -53,13 +66,33 @@ def init_moe(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def _route(p: Params, cfg: ModelConfig, x: jax.Array):
-    """Top-k routing. x: [..., D] -> gates [..., k], idx [..., k], aux."""
+def route(p: Params, cfg: ModelConfig, x: jax.Array):
+    """Top-k routing over all ``num_experts``, in float32.
+
+    x: [..., D] -> (gates [..., k], idx [..., k], probs [..., E]). With
+    ``router_score == "sigmoid"`` (deepseek-v3, ``noaux_tc`` with one
+    group) the scores are ``sigmoid(logits)`` and the choice is made on
+    ``scores + bias``; the gates are the chosen scores without the bias.
+    Either way the gates are normalised over the chosen k and scaled by
+    ``routed_scale``.
+    """
     logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, cfg.experts_per_token)
+    k = cfg.experts_per_token
+    if cfg.router_score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(probs + p["bias"].astype(jnp.float32), k)
+        gates = jnp.take_along_axis(probs, idx, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates * cfg.routed_scale, idx, probs
+
+
+def _route(p: Params, cfg: ModelConfig, x: jax.Array):
+    """Top-k routing. x: [..., D] -> gates [..., k], idx [..., k], aux."""
+    gates, idx, probs = route(p, cfg, x)
     # load-balancing aux loss (Switch-style)
     E = cfg.num_experts
     me = probs.reshape(-1, E).mean(axis=0)                     # [E]
@@ -171,6 +204,40 @@ def apply_moe_gather(p: Params, cfg: ModelConfig, x: jax.Array,
         sh = p["shared"]
         yt = yt + _expert_ffn(sh["wi"], sh["wg"], sh["wo"], xt)
     return yt.reshape(B, S, D), aux
+
+
+def apply_moe_held(p: Params, cfg: ModelConfig, x: jax.Array,
+                   live: Optional[jax.Array] = None):
+    """The routed layer as served: dropless, over the held experts.
+
+    x: [B, S, D]; live: optional [B, S] bool, the tokens whose
+    assignments are counted. Every token is routed over all
+    ``num_experts``; the held experts ``[lo, lo + n)`` (``p["wi"]`` etc.
+    hold exactly those) each run over all of the call's tokens, gated by
+    the token's weight for that expert, zero where it chose another. So
+    nothing is dropped and no token's output depends on another's. At
+    decode the held experts' weights are read once a step whatever the
+    batch, which is what sets the layer's time. Shared experts are added
+    by every share. Returns ``(y, held)``: the share's part of the layer
+    and the number of (token, held expert) assignments of the live
+    tokens, an int32 scalar.
+    """
+    lo, n = cfg.held_experts
+    gates, idx, _ = route(p, cfg, x)                           # [B, S, k]
+    local = idx - lo
+    onehot = jax.nn.one_hot(local, n, dtype=jnp.float32)       # 0 off-share
+    comb = jnp.einsum("bsk,bske->bse", gates, onehot)          # [B, S, n]
+    h = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, p["wg"]))
+    h = h * jnp.einsum("bsd,edf->bsef", x, p["wi"])
+    h = h * comb[..., None].astype(h.dtype)
+    y = jnp.einsum("bsef,efd->bsd", h, p["wo"])
+    if cfg.num_shared_experts:
+        sh = p["shared"]
+        y = y + _expert_ffn(sh["wi"], sh["wg"], sh["wo"], x)
+    hits = (local >= 0) & (local < n)
+    if live is not None:
+        hits = hits & live[..., None]
+    return y, hits.sum(dtype=jnp.int32)
 
 
 def apply_moe(p: Params, cfg: ModelConfig, x: jax.Array):

@@ -46,7 +46,10 @@ Page table layout & eviction contract
 -------------------------------------
 The KV cache is a shared slab ``[L, num_pages, page_size, K * hd]``
 for keys and one for values, the kv heads side by side on the minor
-dim; token ``t`` of the request in slot ``b`` lives at page
+dim; for latent attention (MLA) it is one latent slab ``[L, num_pages,
+page_size, lanes]``, a latent row per token (``Model.init_paged_cache``).
+The engine passes the slab dict through whole, whatever its arrays.
+Token ``t`` of the request in slot ``b`` lives at page
 ``table[b, t // page_size]``, offset ``t % page_size``. Page 0 is the
 null page (never referenced by a live table; absorbs masked writes).
 The decode and prefill steps take the slab donated (on a TPU) and carry
@@ -85,8 +88,9 @@ core, jax 0.9.0), about fifteen microseconds a working tick.
   from building it to the jitted call's return;
 - ``serve.prefill.wait`` (``rid``): the wait for a prompt's first token;
 - ``serve.decode`` (``tokens``: live slots, ``kv_positions``: the sum of
-  ``attention_lengths()``): one decode call, from the capacity checks
-  to the last emitted token, with three children:
+  ``attention_lengths()``; for an MoE model also ``held_expert_tokens``,
+  the call's (token, held expert) assignments): one decode call, from
+  the capacity checks to the last emitted token, with three children:
   ``serve.decode.dispatch`` (the inputs' copies to the device and the
   call), ``serve.decode.wait`` (the wait for its tokens) and
   ``serve.emit`` (handing them to their requests, completions included);
@@ -122,7 +126,6 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..core import serialization
-from ..kernels.ops import check_page_size
 from ..models.model import Model
 from .paging import PageAllocator
 
@@ -146,14 +149,28 @@ def make_prefill(model: Model, max_len: int):
     return prefill
 
 
+def counts_held_experts(model: Model) -> bool:
+    """Whether the decode step counts (token, held expert) assignments:
+    a model with routed experts. Others pay nothing for the counter."""
+    return model.cfg.family == "moe"
+
+
 def make_decode_step(model: Model):
     """decode_step(params, pages, tokens, tables, lengths, mask) ->
-    (next_tokens, pages): ``ContinuousEngine``'s decode program."""
+    (next_tokens, pages): ``ContinuousEngine``'s decode program. For a
+    model with routed experts ``next_tokens`` has one more entry, last:
+    the live slots' (token, held expert) assignments over the layers,
+    so that the count comes back with the tokens in one transfer."""
+
+    counts = counts_held_experts(model)
 
     def decode_step(params, pages, tokens, tables, lengths, mask):
-        logits, pages = model.decode_paged(params, pages, tokens, tables,
-                                           lengths, mask)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pages
+        logits, pages, held = model.decode_paged(params, pages, tokens,
+                                                 tables, lengths, mask)
+        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if counts:
+            toks = jnp.concatenate([toks, held[None]])
+        return toks, pages
 
     return decode_step
 
@@ -323,10 +340,14 @@ class ContinuousEngine:
     """Continuous-batching engine over the paged KV slab.
 
     See the module docstring for the admission / scheduling / eviction
-    contract. Families: dense / vlm / moe (KV-cache caches only).
-    ``page_size`` is also the paged decode kernel's block: a size that
-    kernel cannot tile (see ``ops.check_page_size``; 1-1024 at 16 kv
-    heads of 64) raises ValueError here, not at the first compile.
+    contract. Families: dense / vlm / moe (KV-cache caches only; moe
+    with GQA or latent attention). ``page_size`` is also a block of the
+    paged decode kernel the model runs: a size that kernel cannot take
+    (``Model.check_page_size``: for ``flash_decode_paged`` 1-1024 at 16
+    kv heads of 64, for ``latent_decode_paged`` a multiple of 16 that
+    divides 512) raises ValueError here, not at the first compile.
+    For a model with routed experts ``metrics["held_expert_tokens"]``
+    counts the decode steps' (token, held expert) assignments.
     """
 
     def __init__(self, model: Model, params, *, max_slots: int = 4,
@@ -335,7 +356,7 @@ class ContinuousEngine:
                  eos_id: Optional[int] = None, request_queue=None,
                  lease: bool = False, lease_ttl_s: float = 30.0,
                  worker_id: Optional[str] = None):
-        check_page_size(page_size, model.cfg.num_kv_heads, model.cfg.hd)
+        model.check_page_size(page_size)
         self.model = model
         self.params = params
         self.max_slots = max_slots
@@ -368,6 +389,9 @@ class ContinuousEngine:
         self.metrics = {"admitted": 0, "completed": 0, "preempted": 0,
                         "rejected": 0, "decode_steps": 0,
                         "prefill_chunks": 0}
+        self._counts_held = counts_held_experts(model)
+        if self._counts_held:
+            self.metrics["held_expert_tokens"] = 0
 
         donate = (1,) if jax.default_backend() == "tpu" else ()
         self._decode = jax.jit(make_decode_step(model),
@@ -622,6 +646,10 @@ class ContinuousEngine:
             self.metrics["decode_steps"] += 1
             with TraceAnnotation(SPAN_DECODE_WAIT):
                 toks = np.asarray(toks)
+            if self._counts_held:
+                held = int(toks[-1])
+                self.metrics["held_expert_tokens"] += held
+                span.set_metadata(held_expert_tokens=held)
             with TraceAnnotation(SPAN_EMIT):
                 for idx in decoding:
                     slot = self._slots[idx]

@@ -314,3 +314,76 @@ class TestMamba2:
             return (y ** 2).sum()
         g = jax.grad(loss)(x)
         assert bool(jnp.isfinite(g).all())
+
+
+class TestLatentDecode:
+    """``latent_decode_paged`` (interpret mode) against
+    ``ref.latent_decode_attention``: 16 heads reading one latent row per
+    position (G = 16), rows zero-padded to whole 128-lane tiles as the
+    model stores them, pages scattered over a slab of two layers, ragged
+    lengths with a length-0 row and lengths across the kernel's blocks
+    of ``LATENT_BLOCK`` positions."""
+
+    H, R, ROPE, LANES, PAGE = 16, 96, 32, 256, 16
+
+    def _inputs(self, B, M, dtype, seed=0):
+        rng = np.random.default_rng(seed)
+        P = B * M + 3
+        C = self.R + self.ROPE
+        slab = np.zeros((2, P, self.PAGE, self.LANES), np.float32)
+        slab[..., :C] = rng.standard_normal((2, P, self.PAGE, C))
+        q = np.zeros((B, self.H, self.LANES), np.float32)
+        q[..., :C] = 2.0 * rng.standard_normal((B, self.H, C))
+        table = rng.permutation(np.arange(1, P))[:B * M].reshape(B, M)
+        return (jnp.asarray(q, dtype), jnp.asarray(slab, dtype),
+                jnp.asarray(table, jnp.int32))
+
+    @pytest.mark.parametrize("layer", [0, 1])
+    @pytest.mark.parametrize("lens,dtype", [
+        ([0, 37, 700], jnp.float32),
+        ([512, 1, 513], jnp.float32),
+        ([0, 1024, 16], jnp.bfloat16),
+    ])
+    def test_matches_oracle(self, lens, dtype, layer):
+        from repro.kernels.decode_attention import latent_decode_paged
+        q, slab, table = self._inputs(3, 1024 // self.PAGE, dtype)
+        lengths = jnp.asarray(lens, jnp.int32)
+        scale = (self.R + self.ROPE) ** -0.5
+        got = latent_decode_paged(q, slab, layer, table, lengths,
+                                  sm_scale=scale, v_dim=self.R,
+                                  interpret=True)
+        want = ref.latent_decode_attention(
+            q.astype(jnp.float32), slab.astype(jnp.float32), layer, table,
+            lengths, sm_scale=scale, v_dim=self.R)
+        got, want = np.asarray(got, np.float32), np.asarray(want)
+        assert got.shape == (3, self.H, self.R)
+        # f32: summation order only; bf16: the output's rounding (2**-8
+        # of each value) on top of an f32 computation of bf16 inputs
+        rel = 2e-5 if dtype == jnp.float32 else 2.0 ** -8
+        np.testing.assert_allclose(got, want, rtol=rel,
+                                   atol=2e-5 * np.abs(want).max())
+        for b, n in enumerate(lens):
+            if n == 0:
+                assert not got[b].any()
+
+    def test_oracle_is_attention_over_the_gathered_rows(self):
+        """The oracle itself: one head's output is the softmax-weighted
+        latent part of the rows the table names, up to the length."""
+        q, slab, table = self._inputs(2, 8, jnp.float32, seed=1)
+        lengths = jnp.asarray([5, 100], jnp.int32)
+        o = np.asarray(ref.latent_decode_attention(
+            q, slab, 1, table, lengths, sm_scale=0.1, v_dim=self.R))
+        b, h = 1, 3
+        rows = np.asarray(slab)[1, np.asarray(table)[b]].reshape(-1,
+                                                                self.LANES)
+        rows = rows[:100]
+        s = rows @ np.asarray(q)[b, h] * 0.1
+        p = np.exp(s - s.max())
+        want = (p / p.sum()) @ rows[:, :self.R]
+        np.testing.assert_allclose(o[b, h], want, rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("page", [0, 8, 24, 1024])
+    def test_illegal_page_rejected(self, page):
+        from repro.kernels.decode_attention import check_latent_page_size
+        with pytest.raises(ValueError, match="latent_decode_paged"):
+            check_latent_page_size(page)

@@ -99,7 +99,7 @@ class TestPagedNumerics:
             assert int(np.argmax(np.asarray(lg[0]))) == ref_next[b]
 
         nxt = jnp.asarray(ref_next, jnp.int32)
-        lg_p, _ = m.decode_paged(params, pages, nxt, jnp.asarray(table),
+        lg_p, _, _ = m.decode_paged(params, pages, nxt, jnp.asarray(table),
                                  jnp.asarray(lens, jnp.int32),
                                  jnp.ones((B,), bool))
         for b in range(B):
@@ -115,10 +115,10 @@ class TestPagedNumerics:
         table = np.arange(1, B * M + 1, dtype=np.int32).reshape(B, M)
         toks = jnp.asarray([4, 5, 6], jnp.int32)
         lens = jnp.asarray([3, 2, 1], jnp.int32)
-        all_on, _ = m.decode_paged(params, pages, toks, jnp.asarray(table),
+        all_on, _, _ = m.decode_paged(params, pages, toks, jnp.asarray(table),
                                    lens, jnp.ones((B,), bool))
         # re-run from the SAME slab with slot 1 masked off
-        one_off, _ = m.decode_paged(params, pages, toks, jnp.asarray(table),
+        one_off, _, _ = m.decode_paged(params, pages, toks, jnp.asarray(table),
                                     lens, jnp.asarray([True, False, True]))
         np.testing.assert_allclose(np.asarray(one_off[0]),
                                    np.asarray(all_on[0]), atol=2e-4, rtol=2e-4)

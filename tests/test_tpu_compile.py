@@ -9,8 +9,11 @@ dim 64, bfloat16; 8 slots of 2048 positions) for one chip of a described
 ``v5e:2x2`` topology, with no chip attached. They also compile
 ``ContinuousEngine``'s decode and prefill steps for qwen1.5-0.5b at
 those sizes and check that the page slab stays whole: no temporaries
-and no data move as large as one layer of it. Nothing runs, so nothing
-about results or speed is checked here.
+and no data move as large as one layer of it. The same holds for
+moonlight-16b-a3b's latent kernel ``latent_decode_paged`` and its two
+steps, at the benchmark cell's sizes (24 slots of 7,168 positions, 8 of
+64 experts held). Nothing runs, so nothing about results or speed is
+checked here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -27,10 +30,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.moonlight_16b_a3b import CONFIG as MOONLIGHT
 from repro.configs.qwen1_5_0_5b import CONFIG as QWEN
 from repro.kernels import ops
 from repro.kernels.decode_attention import (MAX_BLOCK_ELEMS, check_page_size,
-                                            flash_decode, flash_decode_paged)
+                                            flash_decode, flash_decode_paged,
+                                            latent_decode_paged)
 from repro.kernels.flash_attention import flash_attention
 from repro.models.model import build_model
 from repro.serve.engine import make_decode_step, make_prefill_step
@@ -138,16 +143,10 @@ def _large_moves(hlo: str, limit: int) -> list:
     return found
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_engine_step_keeps_the_slab_whole(one_chip, monkeypatch, step):
-    """The engine's steps carry the donated slab through the layer loop
-    and write it in place: no temporaries and no copy, transpose or
-    slice as large as one layer of it. A slab passed through the scan as
-    xs, or one whose minor dim is a head of 64, makes XLA slice or relay
-    out each layer's part of it on every step."""
-    monkeypatch.setattr(ops, "pallas_mode", lambda: "tpu")
-    model = build_model(QWEN)
-    M = MAX_LEN // PAGE
+def _engine_step(model, one_chip, step, slots, max_len):
+    """The engine's ``step`` program for ``model``, compiled with the slab
+    donated; returns ``(compiled, pages)`` (shapes)."""
+    M = max_len // PAGE
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -158,15 +157,27 @@ def test_engine_step_keeps_the_slab_whole(one_chip, monkeypatch, step):
 
     params = on_chip(model.abstract_params())
     pages = on_chip(jax.eval_shape(
-        lambda: model.init_paged_cache(SLOTS * M + 1, PAGE)))
+        lambda: model.init_paged_cache(slots * M + 1, PAGE)))
     if step == "decode":
         fn = make_decode_step(model)
-        args = (params, pages, i32(SLOTS), i32(SLOTS, M), i32(SLOTS),
-                jax.ShapeDtypeStruct((SLOTS,), bool, sharding=one_chip))
+        args = (params, pages, i32(slots), i32(slots, M), i32(slots),
+                jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip))
     else:
         fn = make_prefill_step(model)
         args = (params, pages, i32(1, CHUNK), i32(M), i32(), i32())
-    compiled = _lower_and_compile(fn, *args, donate_argnums=(1,))
+    return _lower_and_compile(fn, *args, donate_argnums=(1,)), pages
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_engine_step_keeps_the_slab_whole(one_chip, monkeypatch, step):
+    """The engine's steps carry the donated slab through the layer loop
+    and write it in place: no temporaries and no copy, transpose or
+    slice as large as one layer of it. A slab passed through the scan as
+    xs, or one whose minor dim is a head of 64, makes XLA slice or relay
+    out each layer's part of it on every step."""
+    monkeypatch.setattr(ops, "pallas_mode", lambda: "tpu")
+    model = build_model(QWEN)
+    compiled, pages = _engine_step(model, one_chip, step, SLOTS, MAX_LEN)
     slab = pages["k_pages"]
     layer = (math.prod(slab.shape) // QWEN.num_layers
              * slab.dtype.itemsize)                  # one layer's keys
@@ -191,3 +202,43 @@ def test_flash_attention_forward_compiles(one_chip, seq):
              ((1, seq, H, HD), jnp.bfloat16),
              ((1, seq, K, HD), jnp.bfloat16),
              ((1, seq, K, HD), jnp.bfloat16))
+
+
+#: moonlight-16b-a3b as its benchmark cell serves it
+ML_SLOTS, ML_MAX_LEN = 24, 7168
+
+
+def test_latent_decode_paged_compiles(one_chip):
+    """Moonlight's widths: 16 heads, latent rows of 576 lanes stored in
+    640, 24 slots of 448 pages of 16."""
+    M = ML_MAX_LEN // PAGE
+    lanes = 640
+    _compile(lambda q, s, layer, t, n: latent_decode_paged(
+        q, s, layer, t, n, sm_scale=192 ** -0.5, v_dim=512), one_chip,
+        ((ML_SLOTS, 16, lanes), jnp.bfloat16),
+        ((2, ML_SLOTS * M + 1, PAGE, lanes), jnp.bfloat16),
+        ((), jnp.int32), ((ML_SLOTS, M), jnp.int32),
+        ((ML_SLOTS,), jnp.int32))
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_engine_step_keeps_the_latent_slab_whole(one_chip, monkeypatch,
+                                                 step):
+    """Moonlight at its published widths, all 27 layers, 8 experts held,
+    at the cell's slab: the steps fit the chip, hold temporaries under
+    one layer of the latent slab and move none of it; decode runs the
+    latent kernel."""
+    monkeypatch.setattr(ops, "pallas_mode", lambda: "tpu")
+    model = build_model(MOONLIGHT.replace(experts_held=8))
+    compiled, pages = _engine_step(model, one_chip, step, ML_SLOTS,
+                                   ML_MAX_LEN)
+    slab = pages["kv_pages"]
+    layer = (math.prod(slab.shape) // MOONLIGHT.num_layers
+             * slab.dtype.itemsize)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer, mem
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 0.85 * 16 * 2**30)
+    text = compiled.as_text()
+    assert ("latent_decode_paged" in text) == (step == "decode")
+    assert _large_moves(text, layer) == []
