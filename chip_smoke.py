@@ -58,9 +58,10 @@ PROMPT_LEN, NEW_TOKENS = (128, 1024), (32, 64)
 #: highest matmul precision. TOL bounds what is left, the kernel's own
 #: arithmetic, and is set between the sound kernels and degraded controls
 #: on the same inputs (``scripts/kernel_controls.py``, readings in
-#: PERF.md). Decode scores on the VPU in f32 and reads about 4e-8; it
-#: admits bf16 matmul operands (about 8e-4) and rejects an accumulator or
-#: softmax sum held in bf16 (about 4e-3) and a dropped position (about 1).
+#: PERF.md). The decode kernels score with exact products summed in f32
+#: and read under 1e-7; the limit admits bf16 matmul operands (about
+#: 8e-4) and rejects an accumulator or softmax sum held in bf16 (about
+#: 4e-3) and a dropped position (about 1).
 #: The prefill kernel's dots read about 6e-4 on the chip; with two
 #: 512-key blocks a bf16 accumulator or sum adds only about 1.6e-3, so its
 #: limit sits between. One misplaced position of a 2048-long row moves it
@@ -92,10 +93,11 @@ def require_tpu():
 
 def kernel_inputs(seed: int) -> dict:
     """Seeded bf16 inputs of the three kernels at the served shapes:
-    decode over 8 slots of 2048 positions (ragged lengths, one full row
-    and one of length 3) from a shuffled page table into the last layer
-    of a two-layer slab ``[2, P, page, K * D]``, and the causal prefill
-    of one 1000-token prompt, not a multiple of the 512 block."""
+    decode over 8 slots of 2048 positions (ragged lengths, one full row,
+    one of length 3 and one empty) from a shuffled page table into the
+    last layer of a two-layer slab ``[2, P, page, K * D]``, and the
+    causal prefill of one 1000-token prompt, not a multiple of the 512
+    block."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -110,7 +112,7 @@ def kernel_inputs(seed: int) -> dict:
             jnp.bfloat16)
 
     lengths = rng.integers(1, MAX_LEN + 1, SLOTS)
-    lengths[:2] = (MAX_LEN, 3)
+    lengths[:3] = (MAX_LEN, 3, 0)
     M = MAX_LEN // PAGE
     P = SLOTS * M + 1
     table = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(SLOTS, M),

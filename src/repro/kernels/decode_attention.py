@@ -24,17 +24,21 @@ nor fetched again (an unchanged block index skips the copy). Rows with
 ``lengths == 0`` emit zeros: the accumulator never runs.
 
 :func:`flash_decode_paged` reads K/V through a per-sequence **page
-table** from the serving slab ``[L, P, page, K * hd]``, whole: the
-layer's index and the table arrive by scalar prefetch, and the K/V index
-map resolves block ``j`` of sequence ``b`` to ``(layer, page_table[b,
-j])``, so neither the layer slice nor the page indirection costs a copy.
-A block is one page of all kv heads, ``[page, K * hd]``: the minor dim
-is lane-dense (a multiple of 128 at every served width), which is also
-XLA's compact layout of the slab, so the kernel, the engine's scatter
-and the stored slab share one layout. Per-head scores come from a
-lane-dense multiply and an NT matmul with the 0/1 head indicator
-``[K, K * hd]``; the same indicator spreads each head's probabilities
-and softmax state back over its lanes.
+table** from the serving slab ``[L, P, page, K * hd]``, whole: it stays
+in HBM, and the layer's index, the lengths and the table arrive by
+scalar prefetch. Its time follows the live positions, not slots x
+max_len: one grid step first builds, on the scalar core, the list of
+(slot, block) pairs whose block of ``T`` positions (``paged_block_rows``)
+holds a live position, then walks it. Each block's live pages are copied
+from HBM into a VMEM double buffer, K and V, by async copies started one
+block ahead; a page at or past ``ceil(length / page)`` is never copied.
+The queries sit block-diagonally on the slab's lanes (``[H, K * hd]``,
+each head's query in its kv head's lanes, zeros elsewhere), so one MXU
+product scores every head of a block and one more weights its values.
+Pages that are not whole 8-row tiles cannot be sliced out of the slab by
+an async copy; they take the same arithmetic over a ``(B, M)`` grid of
+single pages fetched by the grid's pipeline, whose dead steps copy
+nothing but still cost a grid step each.
 
 :func:`latent_decode_paged` serves latent attention (MLA): one slab
 ``[L, P, page, C]`` of latent rows, each read as the key of every head
@@ -50,6 +54,7 @@ nor a grid step.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -59,14 +64,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_K = 128
-#: Largest ``page * K * hd`` (``K * hd`` rounded up to 128 lanes) whose
-#: f32 working set fits scoped VMEM: the paged kernel's block is one page
-#: of all kv heads, ``[page, K * hd]``, and the query groups run one at a
-#: time. Measured by compiling ``flash_decode_paged`` for v5e (largest
-#: page that compiled, first that did not): K*hd 1024 at G = 1, 2, 4
-#: 1024/2048; K*hd 2560 at G = 1 512/768; K*hd 2304 at G = 1 512/1024;
-#: K*hd 896 at G = 8 1024 compiled. Every page the bound admits at the
-#: configs' widths compiled (1024, 1170, 409, 455).
+#: Largest ``page * K * hd`` (``K * hd`` rounded up to 128 lanes) that
+#: ``check_page_size`` admits, and the most elements each of
+#: ``flash_decode_paged``'s K and V double buffers holds where the page
+#: allows (``paged_block_rows``). The page bound was measured by compiling
+#: the earlier per-page kernel for v5e, whose f32 working set it kept in
+#: scoped VMEM (largest page that compiled, first that did not): K*hd 1024
+#: at G = 1, 2, 4 1024/2048; K*hd 2560 at G = 1 512/768; K*hd 2304 at
+#: G = 1 512/1024; K*hd 896 at G = 8 1024 compiled. The largest page it
+#: admits at each compile-tested width still compiles.
 MAX_BLOCK_ELEMS = 1 << 20
 
 
@@ -183,68 +189,221 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                         interpret)
 
 
-def _head_indicator(K: int, KD: int, head_dim: int) -> jax.Array:
-    """``[K, KD]`` f32: 1 where lane ``i`` belongs to kv head ``k``."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (K, KD), 1)
-    first = jax.lax.broadcasted_iota(jnp.int32, (K, KD), 0) * head_dim
-    return ((lane >= first) & (lane < first + head_dim)).astype(jnp.float32)
+def _dot_f32(a, b, dims):
+    """``a . b`` with an f32 result: exact products of bf16 operands, all
+    passes (HIGHEST) for f32 ones."""
+    hi = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(a, b, dims, precision=hi,
+                               preferred_element_type=jnp.float32)
 
 
-def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page: int, head_dim: int,
+def _mm_f32(a, b, dims):
+    """``a . b`` in f32 for an f32 ``a`` and ``b`` exact in its own dtype.
+    A bf16 ``b`` takes ``a`` split into two bf16 terms (hi + lo), which
+    keeps ``a`` to about 16 bits where one bf16 pass would round it to
+    8."""
+    dot = functools.partial(_dot_f32, dims=dims)
+    if b.dtype == jnp.float32:
+        return dot(a, b)
+    a_hi = a.astype(b.dtype)
+    a_lo = (a - a_hi.astype(jnp.float32)).astype(b.dtype)
+    return dot(a_hi, b) + dot(a_lo, b)
+
+
+#: Most positions of one slot that one step of ``flash_decode_paged``'s
+#: walk attends (``paged_block_rows``).
+PAGED_BLOCK = 512
+
+
+def paged_block_rows(page_size: int, num_kv_heads: int,
+                     head_dim: int) -> int:
+    """``T``: the positions of one slot that one step of
+    ``flash_decode_paged``'s walk attends, ``T // page_size`` pages copied
+    together into one half of its K and V double buffers. At most
+    ``PAGED_BLOCK``, and small enough that each double buffer holds at
+    most ``MAX_BLOCK_ELEMS`` (512 rows at 1,024 lanes); a multiple of 128
+    where the page allows, so that the scores fill whole lane tiles. A
+    page that is not a multiple of 16 rows (a bf16 tile), or that does
+    not fit that bound, is a block of its own."""
+    lanes = -(-num_kv_heads * head_dim // 128) * 128
+    rows = min(PAGED_BLOCK, MAX_BLOCK_ELEMS // (2 * lanes))
+    if page_size % 16 or page_size >= rows:
+        return page_size
+    unit = math.lcm(page_size, 128)
+    if unit > rows:
+        unit = page_size
+    return rows // unit * unit
+
+
+def _block_rows(G: int, K: int, head_dim: int):
+    """``(group, own)``, each ``[G * K, K * head_dim]``: query row ``r =
+    g * K + kv`` (query head ``kv * G + g``) is of group ``g`` and owns
+    the lanes of kv head ``kv``."""
+    H, KD = G * K, K * head_dim
+    row = jax.lax.broadcasted_iota(jnp.int32, (H, KD), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (H, KD), 1)
+    group = sum((row >= g * K).astype(jnp.int32) for g in range(1, G))
+    first = (row - group * K) * head_dim
+    return group, (lane >= first) & (lane < first + head_dim)
+
+
+def _attend_block(q_ref, b, keys, values, pos0, length, carry, rows, *,
                   sm_scale: float):
+    """One block of rows (positions ``pos0 ..``) of sequence ``b`` into
+    the online-softmax state ``carry = (m, l, acc)`` of all query rows.
+    The queries sit block-diagonally on the slab's lanes (row ``r`` holds
+    its query in its kv head's lanes, zeros elsewhere), so one product
+    scores every head: exact products of the inputs, summed in f32.
+    ``rows`` is ``_block_rows``'s ``(group, own)``."""
+    m, l, acc = carry
+    group, own = rows
+    G, T = q_ref.shape[1], keys.shape[0]
+    q_rows = q_ref[b, 0].astype(jnp.float32)               # [1, KD]
+    for g in range(1, G):
+        q_rows = jnp.where(group == g, q_ref[b, g].astype(jnp.float32),
+                           q_rows)
+    dt = jnp.promote_types(q_ref.dtype, keys.dtype)
+    q_bd = jnp.where(own, q_rows, 0.0).astype(dt)
+    s = _dot_f32(q_bd, keys.astype(dt),
+                 (((1,), (1,)), ((), ()))) * sm_scale       # [H, T]
+    pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    s = jnp.where(pos < length, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)                                 # [H, T]
+    l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    # rows past the length hold whatever the buffer held: zero them,
+    # since a zero weight times a stale NaN is still NaN
+    col = pos0 + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+    v = jnp.where(col < length, values, 0)
+    acc = acc * alpha + _mm_f32(p, v, (((1,), (0,)), ((), ())))
+    return m_new, l, acc
+
+
+def _emit_rows(o_ref, b, acc, l, rows):
+    """Output row ``b``, ``[G, 1, KD]``: each query row's normalised
+    values on its own kv head's lanes, summed over the rows of a group."""
+    group, own = rows
+    out = jnp.where(own, acc / jnp.maximum(l, 1e-30), 0.0)
+    for g in range(o_ref.shape[1]):
+        o_ref[b, g] = jnp.sum(jnp.where(group == g, out, 0.0), axis=0,
+                              keepdims=True).astype(o_ref.dtype)
+
+
+def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  buf, sem, slot_ref, blk_ref, *, page: int, head_dim: int,
+                  sm_scale: float):
+    B, G, _, KD = q_ref.shape
+    T = buf.shape[2]
+    ppb = T // page
+    M = tbl_ref.shape[1]
+    K = KD // head_dim
+    layer = layer_ref[0]
+    rows = _block_rows(G, K, head_dim)
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    # the work list, on the scalar core: (slot, block) of every block
+    # that holds a live position, slot by slot, block by block
+    def add_slot(b, n):
+        def add(j, n):
+            slot_ref[n] = b
+            blk_ref[n] = j
+            return n + 1
+        return jax.lax.fori_loop(0, (len_ref[b] + T - 1) // T, add, n)
+
+    n_items = jax.lax.fori_loop(0, B, add_slot, 0)
+
+    def copies(item, half):
+        """Per page of work item ``item``: whether it holds a live
+        position, and its K and V copies into buffer ``half``."""
+        b = slot_ref[item]
+        out = []
+        for i in range(ppb):
+            j = blk_ref[item] * ppb + i
+            pid = tbl_ref[b, jnp.minimum(j, M - 1)]
+            out.append((j * page < len_ref[b], [
+                pltpu.make_async_copy(src.at[layer, pid],
+                                      buf.at[c, half, pl.ds(i * page, page)],
+                                      sem.at[half])
+                for c, src in enumerate((k_hbm, v_hbm))]))
+        return out
+
+    def start(item, half):
+        for live, cps in copies(item, half):
+            @pl.when(live)
+            def _():
+                for cp in cps:
+                    cp.start()
+
+    def wait(item, half):
+        for live, cps in copies(item, half):
+            @pl.when(live)
+            def _():
+                for cp in cps:
+                    cp.wait()
+
+    @pl.when(n_items > 0)
+    def _():
+        start(0, 0)
+
+    def body(item, carry):
+        half = item % 2
+
+        @pl.when(item + 1 < n_items)
+        def _():
+            start(item + 1, 1 - half)
+
+        wait(item, half)
+        b, blk = slot_ref[item], blk_ref[item]
+        length = len_ref[b]
+        fresh = blk == 0
+        carry = tuple(jnp.where(fresh, x0, x) for x0, x in zip(init, carry))
+        m, l, acc = _attend_block(q_ref, b, buf[0, half], buf[1, half],
+                                  blk * T, length, carry, rows,
+                                  sm_scale=sm_scale)
+
+        @pl.when((blk + 1) * T >= length)
+        def _():
+            _emit_rows(o_ref, b, acc, l, rows)
+
+        return m, l, acc
+
+    H = G * K
+    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, KD), jnp.float32))
+    jax.lax.fori_loop(0, n_items, body, init)
+
+
+def _paged_grid_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_ref, v_ref,
+                       o_ref, m_scr, l_scr, acc_scr, *, head_dim: int,
+                       sm_scale: float):
+    """The same attention over a ``(B, M)`` grid of single pages fetched
+    by the grid's own pipeline, for pages that are not whole 8-row tiles
+    (which an async copy cannot slice out of the slab)."""
     del layer_ref, tbl_ref  # read by the index maps only
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+    b, j = pl.program_id(0), pl.program_id(1)
+    page = k_ref.shape[2]
     length = len_ref[b]
     G, KD = q_ref.shape[1], q_ref.shape[3]
-    K = KD // head_dim
+    rows = _block_rows(G, KD // head_dim, head_dim)
 
     @pl.when(j == 0)
-    def init():
+    def _():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     @pl.when(j * page < length)
-    def compute():
-        k = k_ref[0, 0].astype(jnp.float32)                # [page, KD]
-        v = v_ref[0, 0].astype(jnp.float32)
-        ind = _head_indicator(K, KD, head_dim)
-        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
-        valid = pos < length                               # [page, 1]
-        hi = jax.lax.Precision.HIGHEST
-
-        def group(g, carry):
-            q = q_ref[0, g].astype(jnp.float32) * sm_scale  # [1, KD]
-            s = jax.lax.dot_general(
-                k * q, ind, (((1,), (1,)), ((), ())), precision=hi,
-                preferred_element_type=jnp.float32)        # [page, K]
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_scr[g]                              # [1, K]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)                         # [page, K]
-            l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=0, keepdims=True)
-            p_lanes = jnp.dot(p, ind, precision=hi,
-                              preferred_element_type=jnp.float32)
-            a_lanes = jnp.dot(alpha, ind, precision=hi,
-                              preferred_element_type=jnp.float32)
-            acc_scr[g] = acc_scr[g] * a_lanes + jnp.sum(
-                p_lanes * v, axis=0, keepdims=True)
-            m_scr[g] = m_new
-            return carry
-
-        jax.lax.fori_loop(0, G, group, 0)
+    def _():
+        carry = _attend_block(q_ref, 0, k_ref[0, 0], v_ref[0, 0], j * page,
+                              length, (m_scr[...], l_scr[...], acc_scr[...]),
+                              rows, sm_scale=sm_scale)
+        m_scr[...], l_scr[...], acc_scr[...] = carry
 
     @pl.when(j == pl.num_programs(1) - 1)
-    def emit():
-        ind = _head_indicator(K, KD, head_dim)
-        for g in range(G):
-            l = jnp.dot(jnp.maximum(l_scr[g], 1e-30), ind,
-                        precision=jax.lax.Precision.HIGHEST,
-                        preferred_element_type=jnp.float32)  # [1, KD]
-            o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
+    def _():
+        _emit_rows(o_ref, 0, acc_scr[...], l_scr[...], rows)
 
 
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -260,42 +419,62 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     ``b`` lives at ``[layer, page_table[b, t // page_size], t %
     page_size, kv * D:(kv + 1) * D]``; table entries at or past
     ``ceil(lengths[b] / page_size)`` are never read. Query head ``h``
-    reads kv head ``h // (H // K)``.
+    reads kv head ``h // (H // K)``. Scores are exact products of the
+    inputs summed in f32, the softmax is f32; rows with ``lengths == 0``
+    emit zeros.
     """
     B, H, D = q.shape
     _, _, page, KD = k_pages.shape
     K = KD // D
     G = H // K
+    M = page_table.shape[1]
     scale = sm_scale if sm_scale is not None else D ** -0.5
     # group-major queries on the slab's lanes: qg[b, g, 0, kv * D + d]
     # is query head kv * G + g
     qg = jnp.swapaxes(q.reshape(B, K, G, D), 1, 2).reshape(B, G, 1, KD)
+    whole = pl.BlockSpec((B, G, 1, KD), lambda *_: (0, 0, 0, 0))
+    if page % 8:
+        def kv_map(b, j, lyr, lens, tbl):
+            # an empty row reads the null page, not its dead table entry
+            pid = tbl[b, jnp.minimum(j, _last_block(lens[b], page))]
+            return (lyr[0], jnp.where(lens[b] > 0, pid, 0), 0, 0)
 
-    def kv_map(b, j, lyr, lens, tbl):
-        return (lyr[0], tbl[b, jnp.minimum(j, _last_block(lens[b], page))],
-                0, 0)
+        def row_map(b, j, *_):
+            return (b, 0, 0, 0)
 
-    def qo_map(b, j, *_):
-        return (b, 0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, page_table.shape[1]),
-        in_specs=[pl.BlockSpec((1, G, 1, KD), qo_map),
-                  pl.BlockSpec((1, 1, page, KD), kv_map),
-                  pl.BlockSpec((1, 1, page, KD), kv_map)],
-        out_specs=pl.BlockSpec((1, G, 1, KD), qo_map),
-        scratch_shapes=[pltpu.VMEM((G, 1, K), jnp.float32),
-                        pltpu.VMEM((G, 1, K), jnp.float32),
-                        pltpu.VMEM((G, 1, KD), jnp.float32)],
-    )
-    kernel = functools.partial(_paged_kernel, page=page, head_dim=D,
-                               sm_scale=scale)
+        kernel = functools.partial(_paged_grid_kernel, head_dim=D,
+                                   sm_scale=scale)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, M),
+            in_specs=[pl.BlockSpec((1, G, 1, KD), row_map),
+                      pl.BlockSpec((1, 1, page, KD), kv_map),
+                      pl.BlockSpec((1, 1, page, KD), kv_map)],
+            out_specs=pl.BlockSpec((1, G, 1, KD), row_map),
+            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, KD), jnp.float32)])
+    else:
+        T = paged_block_rows(page, K, D)
+        n_max = B * -(-M * page // T)
+        kernel = functools.partial(_paged_kernel, page=page, head_dim=D,
+                                   sm_scale=scale)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[pltpu.VMEM((2, 2, T, KD), k_pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((n_max,), jnp.int32),
+                            pltpu.SMEM((n_max,), jnp.int32)])
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, 1, KD), q.dtype),
-        interpret=interpret,
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="flash_decode_paged",
     )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
       page_table.astype(jnp.int32), qg, k_pages, v_pages)
     return jnp.swapaxes(o.reshape(B, G, K, D), 1, 2).reshape(B, H, D)
@@ -317,27 +496,6 @@ def check_latent_page_size(page_size: int) -> None:
             f"page_size={page_size} is not a legal block of "
             f"latent_decode_paged: use a multiple of 16 that divides "
             f"{LATENT_BLOCK}")
-
-
-def _dot_f32(a, b, dims):
-    """``a . b`` with an f32 result: exact products of bf16 operands, all
-    passes (HIGHEST) for f32 ones."""
-    hi = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
-    return jax.lax.dot_general(a, b, dims, precision=hi,
-                               preferred_element_type=jnp.float32)
-
-
-def _mm_f32(a, b, dims):
-    """``a . b`` in f32 for an f32 ``a`` and ``b`` exact in its own dtype.
-    A bf16 ``b`` takes ``a`` split into two bf16 terms (hi + lo), which
-    keeps ``a`` to about 16 bits where one bf16 pass would round it to
-    8."""
-    dot = functools.partial(_dot_f32, dims=dims)
-    if b.dtype == jnp.float32:
-        return dot(a, b)
-    a_hi = a.astype(b.dtype)
-    a_lo = (a - a_hi.astype(jnp.float32)).astype(b.dtype)
-    return dot(a_hi, b) + dot(a_lo, b)
 
 
 def _latent_kernel(layer_ref, len_ref, tbl_ref, slot_ref, blk_ref, n_ref,

@@ -187,10 +187,21 @@ def apply_attention_decode(p: Params, cfg: ModelConfig, x: jax.Array,
     return out, cache_k, cache_v
 
 
+def paged_decode_rows(page_table: jax.Array, lengths: jax.Array,
+                      slot_mask: jax.Array, page: int):
+    """Where one decode step writes and what it attends, the same in every
+    layer: ``(pid, off, att_len)``, each [B]. The new token lands at row
+    ``off`` of page ``pid`` (a masked slot's at the null page 0) and
+    ``att_len`` positions are attended (0 for a masked slot)."""
+    B = lengths.shape[0]
+    pid = jnp.where(slot_mask, page_table[jnp.arange(B), lengths // page], 0)
+    return pid, lengths % page, jnp.where(slot_mask, lengths + 1, 0)
+
+
 def apply_attention_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
                                  k_pages: jax.Array, v_pages: jax.Array,
                                  layer, page_table: jax.Array,
-                                 lengths: jax.Array, slot_mask: jax.Array,
+                                 lengths: jax.Array, rows,
                                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode of layer ``layer`` against the shared page slab.
 
@@ -198,28 +209,24 @@ def apply_attention_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
     shared by every sequence, page 0 reserved as the null page; layer:
     int32 scalar; page_table: [B, M] per-slot page ids; lengths: [B]
     valid cache entries (the new token is written at position
-    ``lengths``); slot_mask: [B] bool — False rows are idle serving
-    slots: their K/V write is redirected to the null page and their
-    attention length forced to 0, so a dead slot can neither corrupt a
-    live sequence's pages nor read stale ones. The write is one scatter
-    into the slab as passed in, so a donated slab carried through the
-    layer loop is updated in place.
+    ``lengths``); rows: ``paged_decode_rows`` of the step, made once
+    outside the layer loop. Idle serving slots (False in its
+    ``slot_mask``) write to the null page and attend nothing, so a dead
+    slot can neither corrupt a live sequence's pages nor read stale
+    ones. The write is one scatter into the slab as passed in, so a
+    donated slab carried through the layer loop is updated in place.
 
     Equivalent to ``apply_attention_decode`` with the "onehot" policy on
     the gathered contiguous cache — per-slot ragged lengths (and thus
     ragged rope positions) are the normal case here, not an edge case.
     """
     B = x.shape[0]
-    page = k_pages.shape[2]
+    pid, off, att_len = rows
     q, k, v = _project_qkv(p, cfg, x, lengths[:, None], use_rope=True)
-    pid = page_table[jnp.arange(B), lengths // page]           # [B]
-    pid = jnp.where(slot_mask, pid, 0)
-    off = lengths % page
     k_pages = k_pages.at[layer, pid, off].set(
         k.reshape(B, -1).astype(k_pages.dtype))
     v_pages = v_pages.at[layer, pid, off].set(
         v.reshape(B, -1).astype(v_pages.dtype))
-    att_len = jnp.where(slot_mask, lengths + 1, 0)
     o = ops.paged_decode_attention(q[:, 0], k_pages, v_pages, layer,
                                    page_table, att_len)
     out = jnp.einsum("bf,fd->bd", o.reshape(B, -1), p["wo"])[:, None, :]

@@ -471,6 +471,14 @@ class Model:
             ops.check_page_size(page_size, self.cfg.num_kv_heads,
                                 self.cfg.hd)
 
+    def decode_block_rows(self, page_size: int) -> int:
+        """Positions of one slot that one step of the paged decode
+        kernel's walk over live blocks attends."""
+        if self.cfg.is_mla:
+            return ops.LATENT_BLOCK
+        return ops.paged_block_rows(page_size, self.cfg.num_kv_heads,
+                                    self.cfg.hd)
+
     def init_paged_cache(self, num_pages: int, page_size: int
                          ) -> Dict[str, jax.Array]:
         """Zeroed page slab. GQA/MHA: {'k_pages','v_pages': [L, P, page,
@@ -561,10 +569,13 @@ class Model:
                     slot_mask)
                 return a, {"kv_pages": kv}
         else:
+            rows = L.paged_decode_rows(page_tables, lengths, slot_mask,
+                                       pages["k_pages"].shape[2])
+
             def attend(p, h, pages, i):
                 a, kp, vp = L.apply_attention_decode_paged(
                     p, cfg, h, pages["k_pages"], pages["v_pages"], i,
-                    page_tables, lengths, slot_mask)
+                    page_tables, lengths, rows)
                 return a, {"k_pages": kp, "v_pages": vp}
 
         x, pages, held = self._layers(params, x, pages, attend,

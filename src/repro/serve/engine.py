@@ -88,12 +88,15 @@ core, jax 0.9.0), about fifteen microseconds a working tick.
   from building it to the jitted call's return;
 - ``serve.prefill.wait`` (``rid``): the wait for a prompt's first token;
 - ``serve.decode`` (``tokens``: live slots, ``kv_positions``: the sum of
-  ``attention_lengths()``; for an MoE model also ``held_expert_tokens``,
-  the call's (token, held expert) assignments): one decode call, from
-  the capacity checks to the last emitted token, with three children:
-  ``serve.decode.dispatch`` (the inputs' copies to the device and the
-  call), ``serve.decode.wait`` (the wait for its tokens) and
-  ``serve.emit`` (handing them to their requests, completions included);
+  ``attention_lengths()``, ``attn_blocks``: the blocks of
+  ``Model.decode_block_rows`` positions that the decode kernel walks in
+  each layer, ``ceil(length / block)`` summed over the slots; for an MoE
+  model also ``held_expert_tokens``, the call's (token, held expert)
+  assignments): one decode call, from the capacity checks to the last
+  emitted token, with three children: ``serve.decode.dispatch`` (the
+  inputs' copies to the device and the call), ``serve.decode.wait``
+  (the wait for its tokens) and ``serve.emit`` (handing them to their
+  requests, completions included);
 - ``serve.kv.reply`` (``rid``): a reply's lease release and push;
 - ``serve.kv.renew``: a round of lease renewals.
 
@@ -389,6 +392,7 @@ class ContinuousEngine:
         self.metrics = {"admitted": 0, "completed": 0, "preempted": 0,
                         "rejected": 0, "decode_steps": 0,
                         "prefill_chunks": 0}
+        self._attn_block = model.decode_block_rows(page_size)
         self._counts_held = counts_held_experts(model)
         if self._counts_held:
             self.metrics["held_expert_tokens"] = 0
@@ -636,8 +640,10 @@ class ContinuousEngine:
                         if s is not None and s.state == "decode"]
             if not decoding:
                 return
-            span.set_metadata(tokens=len(decoding),
-                              kv_positions=int(self.attention_lengths().sum()))
+            att = self.attention_lengths()
+            span.set_metadata(
+                tokens=len(decoding), kv_positions=int(att.sum()),
+                attn_blocks=int((-(-att // self._attn_block)).sum()))
             with TraceAnnotation(SPAN_DECODE_DISPATCH):
                 toks, self._pages = self._decode(
                     self.params, self._pages, jnp.asarray(self._tokens),

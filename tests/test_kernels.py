@@ -9,7 +9,8 @@ import pytest
 from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import flash_decode, flash_decode_paged
+from repro.kernels.decode_attention import (flash_decode, flash_decode_paged,
+                                            paged_block_rows)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba2_scan import mamba2_chunked
 from repro.kernels.rwkv6_scan import rwkv6_chunked
@@ -228,6 +229,91 @@ class TestPagedDecode:
         o = flash_decode_paged(q, kp, vp, 1, table, lengths, interpret=True)
         np.testing.assert_allclose(np.array(o), np.array(o_ref),
                                    atol=2e-5, rtol=2e-5)
+
+    @staticmethod
+    def _slab(B, H, K, D, page, lens, seed=0):
+        """Random q and a two-layer slab read at layer 1, each slot's pages
+        scattered over it: ``(q, k_pages, v_pages, table, lengths)``."""
+        rng = np.random.default_rng(seed)
+        M = -(-max(max(lens), 1) // page) + 1
+        P = B * M + 3
+        table = rng.permutation(np.arange(1, P))[:B * M].reshape(B, M)
+        k_pages, v_pages = (rng.standard_normal((2, P, page, K * D))
+                            .astype(np.float32) for _ in range(2))
+        q = rng.standard_normal((B, H, D)).astype(np.float32)
+        return (q, k_pages, v_pages, table.astype(np.int32),
+                np.asarray(lens, np.int32))
+
+    @pytest.mark.parametrize("page,H,K,lens", [
+        (16, 4, 4, "edges"),      # G = 1
+        (16, 8, 4, "edges"),      # G = 2
+        (16, 16, 4, "edges"),     # G = 4
+        (128, 8, 4, "edges"),
+        (24, 8, 4, "edges"),      # not whole bf16 tiles: a page a block
+        (7, 8, 4, "edges"),       # not whole 8-row tiles: the page grid
+        (16, 8, 4, "one_live"),
+        (128, 16, 4, "one_live"),
+    ])
+    def test_walk_matches_oracle(self, page, H, K, lens):
+        """Lengths one short of, at and one past a block of the walk, and
+        a batch with one live slot: against the oracle, zero-length rows
+        exactly zero."""
+        D = 32
+        T = paged_block_rows(page, K, D)
+        lens = [T - 1, T, T + 1] if lens == "edges" else [0, 0, T + 1, 0]
+        q, kp, vp, table, lengths = (jnp.asarray(x) for x in self._slab(
+            len(lens), H, K, D, page, lens))
+        got = np.asarray(flash_decode_paged(q, kp, vp, 1, table, lengths,
+                                            interpret=True))
+        want = np.asarray(ref.paged_decode_attention(q, kp, vp, 1, table,
+                                                     lengths))
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        for b, n in enumerate(lens):
+            if n == 0:
+                assert not got[b].any()
+
+    @pytest.mark.parametrize("dead", ["nan", "outside"])
+    @pytest.mark.parametrize("page", [16, 128, 7])
+    def test_dead_pages_are_never_read(self, page, dead):
+        """Every page but the slots' live ones holds NaN; the table's dead
+        entries name such pages (``nan``) or pages past the slab, which
+        the interpreter refuses to copy (``outside``). The output stays
+        finite and matches the oracle on the clean slab."""
+        H, K, D = 8, 4, 32
+        T = paged_block_rows(page, K, D)
+        lens = [0, 3, T + page + 1, 2 * page - 1]
+        q, kp, vp, table, lengths = self._slab(len(lens), H, K, D, page,
+                                               lens, seed=1)
+        n_live = [-(-n // page) for n in lens]
+        live = {int(p) for b, n in enumerate(n_live) for p in table[b, :n]}
+        dead_pages = np.array([p not in live for p in range(kp.shape[1])])
+        kp_nan, vp_nan, tbl = kp.copy(), vp.copy(), table.copy()
+        kp_nan[:, dead_pages] = np.nan
+        vp_nan[:, dead_pages] = np.nan
+        if dead == "outside":
+            for b, n in enumerate(n_live):
+                tbl[b, n:] = kp.shape[1] + 1000
+        got = np.asarray(flash_decode_paged(
+            jnp.asarray(q), jnp.asarray(kp_nan), jnp.asarray(vp_nan), 1,
+            jnp.asarray(tbl), jnp.asarray(lengths), interpret=True))
+        want = np.asarray(ref.paged_decode_attention(
+            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), 1,
+            jnp.asarray(table), jnp.asarray(lengths)))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("page,kv_heads,hd,rows", [
+        (16, 16, 64, 512),        # qwen1.5-0.5b: 32 pages a block
+        (128, 16, 64, 512),
+        (48, 16, 64, 384),        # whole 128-lane tiles of scores
+        (24, 16, 64, 24),         # not whole bf16 tiles
+        (1024, 16, 64, 1024),     # past the double buffer's budget
+        (16, 20, 128, 128),       # qwen1.5-4b: 2,560 lanes
+        (16, 8, 112, 512),        # kimi-k2: 896 lanes
+    ])
+    def test_block_rows_follow_the_shape(self, page, kv_heads, hd, rows):
+        T = paged_block_rows(page, kv_heads, hd)
+        assert T == rows and T % page == 0
 
     def test_gather_round_trip(self):
         """ref.paged gather reconstructs the contiguous cache exactly:

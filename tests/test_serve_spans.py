@@ -13,6 +13,7 @@ from jax.profiler import TraceAnnotation
 from repro.configs.qwen1_5_0_5b import SMOKE
 from repro.core import reset_session
 from repro.core.queues import Queue
+from repro.kernels.decode_attention import paged_block_rows
 from repro.models.model import build_model
 from repro.serve import ContinuousEngine, ServeClient
 from repro.serve import engine as engine_mod
@@ -111,13 +112,15 @@ def served(model_params, tmp_path_factory):
     eng.submit([3, 4, 5, 6, 7], 2)             # compile both programs
     eng.run_until_idle()
     eng._store = _CmdSpans(eng._store)
-    calls = []
+    calls, blocks = [], []
     decode = eng._decode
+    T = paged_block_rows(eng.page_size, SMOKE.num_kv_heads, SMOKE.hd)
 
     def recorded(params, pages, tokens, tables, lengths, mask):
         live = np.asarray(mask)
-        calls.append((int(live.sum()),
-                      int(np.where(live, np.asarray(lengths) + 1, 0).sum())))
+        att = np.where(live, np.asarray(lengths) + 1, 0)
+        calls.append((int(live.sum()), int(att.sum())))
+        blocks.append(sum(-(-int(n) // T) for n in att))
         return decode(params, pages, tokens, tables, lengths, mask)
     eng._decode = recorded
 
@@ -130,7 +133,7 @@ def served(model_params, tmp_path_factory):
             eng.step()
     replies = [client.result(r, timeout=5.0) for r in rids]
     return {"events": _events(trace_dir), "replies": replies,
-            "calls": calls}
+            "calls": calls, "blocks": blocks}
 
 
 def _named(served, name):
@@ -153,6 +156,15 @@ def test_decode_span_counts_live_slots_and_positions(served):
            for e in _named(served, "serve.decode")]
     assert got == served["calls"]
     assert max(t for t, _ in got) > 1      # the batch held several
+
+
+def test_decode_span_counts_the_kernels_blocks(served):
+    """``attn_blocks`` is the number of blocks of the decode kernel's
+    walk (``paged_block_rows`` positions each) over the call's live
+    lengths."""
+    got = [e["stats"]["attn_blocks"] for e in _named(served, "serve.decode")]
+    assert got == served["blocks"]
+    assert max(got) > 1
 
 
 def test_decode_span_holds_one_call(served):

@@ -185,6 +185,9 @@ def test_engine_step_keeps_the_slab_whole(one_chip, monkeypatch, step):
     assert temp < 2 * layer, f"{temp} B of temporaries"
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == (step == "decode")
+    kernel = re.search(r"^\s*%flash_decode_paged\S* = .* custom-call\(", text,
+                       re.M)
+    assert (kernel is not None) == (step == "decode")
     assert _large_moves(text, layer) == []
 
 
