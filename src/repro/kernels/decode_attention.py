@@ -23,32 +23,32 @@ valid block, so blocks past the length are neither computed (``pl.when``)
 nor fetched again (an unchanged block index skips the copy). Rows with
 ``lengths == 0`` emit zeros: the accumulator never runs.
 
-:func:`flash_decode_paged` reads K/V through a per-sequence **page
-table** from the serving slab ``[L, P, page, K * hd]``, whole: it stays
-in HBM, and the layer's index, the lengths and the table arrive by
-scalar prefetch. Its time follows the live positions, not slots x
-max_len: one grid step first builds, on the scalar core, the list of
-(slot, block) pairs whose block of ``T`` positions (``paged_block_rows``)
-holds a live position, then walks it. Each block's live pages are copied
-from HBM into a VMEM double buffer, K and V, by async copies started one
-block ahead; a page at or past ``ceil(length / page)`` is never copied.
-The queries sit block-diagonally on the slab's lanes (``[H, K * hd]``,
-each head's query in its kv head's lanes, zeros elsewhere), so one MXU
-product scores every head of a block and one more weights its values.
-Pages that are not whole 8-row tiles cannot be sliced out of the slab by
-an async copy; they take the same arithmetic over a ``(B, M)`` grid of
-single pages fetched by the grid's pipeline, whose dead steps copy
-nothing but still cost a grid step each.
+The two paged kernels read the serving slab ``[L, P, page, lanes]``
+through a per-sequence **page table**, whole: it stays in HBM, and the
+layer's index, the lengths and the table arrive by scalar prefetch.
+Both run one walk (``_walk_live_blocks``) whose time follows the live
+positions, not slots x max_len: one grid step first builds, on the
+scalar core, the list of (slot, block) pairs whose block of ``T``
+positions (``paged_block_rows``) holds a live position, then walks it.
+Each block's live pages are copied from HBM into a VMEM double buffer
+by async copies started one block ahead; a page at or past
+``ceil(length / page)`` is never copied. The online-softmax state is
+reset on a slot's first block and emitted on its last. The kernels
+differ only in how they score a block:
 
-:func:`latent_decode_paged` serves latent attention (MLA): one slab
-``[L, P, page, C]`` of latent rows, each read as the key of every head
-(all ``C`` lanes) and as its value (the first ``v_dim``). Its time
-follows the live positions, not slots x max_len: one grid step runs a
-loop over the batch's live blocks of ``LATENT_BLOCK`` positions, a
-flattened list of (slot, block) made from the lengths before the call.
-Each block's pages are copied from HBM into a VMEM double buffer by
-async copies started one block ahead, so dead pages cost neither a copy
-nor a grid step.
+- :func:`flash_decode_paged` (GQA/MHA) reads K and V from two slabs
+  ``[L, P, page, K * hd]``. The queries sit block-diagonally on the
+  slab's lanes (``[H, K * hd]``, each head's query in its kv head's
+  lanes, zeros elsewhere), so one MXU product scores every head of a
+  block and one more weights its values. Pages that are not whole 8-row
+  tiles cannot be sliced out of the slab by an async copy; they take the
+  same arithmetic over a ``(B, M)`` grid of single pages fetched by the
+  grid's pipeline, whose dead steps copy nothing but still cost a grid
+  step each.
+- :func:`latent_decode_paged` (latent attention, MLA) reads one slab
+  ``[L, P, page, C]`` of latent rows, each the key of every head (all
+  ``C`` lanes) and its value (the first ``v_dim``): one product scores
+  all heads of a block.
 """
 
 from __future__ import annotations
@@ -65,9 +65,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 DEFAULT_BLOCK_K = 128
 #: Largest ``page * K * hd`` (``K * hd`` rounded up to 128 lanes) that
-#: ``check_page_size`` admits, and the most elements each of
-#: ``flash_decode_paged``'s K and V double buffers holds where the page
-#: allows (``paged_block_rows``). The page bound was measured by compiling
+#: ``check_page_size`` admits, and the most elements each double buffer of
+#: the walk over live blocks (K and V, or the latent rows) holds where the
+#: page allows (``paged_block_rows``). The page bound was measured by compiling
 #: the earlier per-page kernel for v5e, whose f32 working set it kept in
 #: scoped VMEM (largest page that compiled, first that did not): K*hd 1024
 #: at G = 1, 2, 4 1024/2048; K*hd 2560 at G = 1 512/768; K*hd 2304 at
@@ -210,22 +210,21 @@ def _mm_f32(a, b, dims):
     return dot(a_hi, b) + dot(a_lo, b)
 
 
-#: Most positions of one slot that one step of ``flash_decode_paged``'s
-#: walk attends (``paged_block_rows``).
+#: Most positions of one slot that one step of the walk over live blocks
+#: attends (``paged_block_rows``).
 PAGED_BLOCK = 512
 
 
-def paged_block_rows(page_size: int, num_kv_heads: int,
-                     head_dim: int) -> int:
-    """``T``: the positions of one slot that one step of
-    ``flash_decode_paged``'s walk attends, ``T // page_size`` pages copied
-    together into one half of its K and V double buffers. At most
+def paged_block_rows(page_size: int, lanes: int) -> int:
+    """``T``: the positions of one slot that one step of the walk over
+    live blocks attends, ``T // page_size`` pages of a slab of ``lanes``
+    lanes copied together into one half of a double buffer. At most
     ``PAGED_BLOCK``, and small enough that each double buffer holds at
     most ``MAX_BLOCK_ELEMS`` (512 rows at 1,024 lanes); a multiple of 128
     where the page allows, so that the scores fill whole lane tiles. A
     page that is not a multiple of 16 rows (a bf16 tile), or that does
     not fit that bound, is a block of its own."""
-    lanes = -(-num_kv_heads * head_dim // 128) * 128
+    lanes = -(-lanes // 128) * 128
     rows = min(PAGED_BLOCK, MAX_BLOCK_ELEMS // (2 * lanes))
     if page_size % 16 or page_size >= rows:
         return page_size
@@ -247,25 +246,13 @@ def _block_rows(G: int, K: int, head_dim: int):
     return group, (lane >= first) & (lane < first + head_dim)
 
 
-def _attend_block(q_ref, b, keys, values, pos0, length, carry, rows, *,
-                  sm_scale: float):
-    """One block of rows (positions ``pos0 ..``) of sequence ``b`` into
-    the online-softmax state ``carry = (m, l, acc)`` of all query rows.
-    The queries sit block-diagonally on the slab's lanes (row ``r`` holds
-    its query in its kv head's lanes, zeros elsewhere), so one product
-    scores every head: exact products of the inputs, summed in f32.
-    ``rows`` is ``_block_rows``'s ``(group, own)``."""
+def _fold_block(carry, s, values, pos0, length):
+    """Fold one block of positions ``pos0 ..`` into the online-softmax
+    state ``carry = (m, l, acc)`` of all query rows: scores ``s`` [H, T],
+    scaled, and values [T, V]; positions at or past ``length`` weigh
+    nothing. The weights take the values in f32 (``_mm_f32``)."""
     m, l, acc = carry
-    group, own = rows
-    G, T = q_ref.shape[1], keys.shape[0]
-    q_rows = q_ref[b, 0].astype(jnp.float32)               # [1, KD]
-    for g in range(1, G):
-        q_rows = jnp.where(group == g, q_ref[b, g].astype(jnp.float32),
-                           q_rows)
-    dt = jnp.promote_types(q_ref.dtype, keys.dtype)
-    q_bd = jnp.where(own, q_rows, 0.0).astype(dt)
-    s = _dot_f32(q_bd, keys.astype(dt),
-                 (((1,), (1,)), ((), ()))) * sm_scale       # [H, T]
+    T = s.shape[1]
     pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
     s = jnp.where(pos < length, s, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -280,6 +267,27 @@ def _attend_block(q_ref, b, keys, values, pos0, length, carry, rows, *,
     return m_new, l, acc
 
 
+def _attend_block(q_ref, b, keys, values, pos0, length, carry, rows, *,
+                  sm_scale: float):
+    """One block of rows (positions ``pos0 ..``) of sequence ``b`` into
+    the online-softmax state ``carry = (m, l, acc)`` of all query rows.
+    The queries sit block-diagonally on the slab's lanes (row ``r`` holds
+    its query in its kv head's lanes, zeros elsewhere), so one product
+    scores every head: exact products of the inputs, summed in f32.
+    ``rows`` is ``_block_rows``'s ``(group, own)``."""
+    group, own = rows
+    G = q_ref.shape[1]
+    q_rows = q_ref[b, 0].astype(jnp.float32)               # [1, KD]
+    for g in range(1, G):
+        q_rows = jnp.where(group == g, q_ref[b, g].astype(jnp.float32),
+                           q_rows)
+    dt = jnp.promote_types(q_ref.dtype, keys.dtype)
+    q_bd = jnp.where(own, q_rows, 0.0).astype(dt)
+    s = _dot_f32(q_bd, keys.astype(dt),
+                 (((1,), (1,)), ((), ()))) * sm_scale       # [H, T]
+    return _fold_block(carry, s, values, pos0, length)
+
+
 def _emit_rows(o_ref, b, acc, l, rows):
     """Output row ``b``, ``[G, 1, KD]``: each query row's normalised
     values on its own kv head's lanes, summed over the rows of a group."""
@@ -290,20 +298,31 @@ def _emit_rows(o_ref, b, acc, l, rows):
                               keepdims=True).astype(o_ref.dtype)
 
 
-def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  buf, sem, slot_ref, blk_ref, *, page: int, head_dim: int,
-                  sm_scale: float):
-    B, G, _, KD = q_ref.shape
-    T = buf.shape[2]
+def _walk_live_blocks(layer_ref, len_ref, tbl_ref, srcs, o_ref, buf, sem,
+                      slot_ref, blk_ref, *, acc_shape, attend, emit):
+    """The walk of both paged decode kernels over the live blocks of a
+    batch, in one grid step.
+
+    srcs: the HBM slabs ``[L, P, page, lanes]`` read at layer
+    ``layer_ref[0]`` through the table (``(k, v)``, or the latent slab
+    alone); buf: their VMEM double buffer ``[len(srcs), 2, T, lanes]``,
+    sem its two DMA semaphores; slot_ref, blk_ref: SMEM work list. First,
+    on the scalar core, the (slot, block) pairs whose block of ``T``
+    positions holds a live position, slot by slot, block by block. Then
+    for each pair: the block's live pages copied into one half of the
+    buffer (started one pair ahead; a page at or past ``ceil(length /
+    page)`` is never copied); the online-softmax state ``(m, l, acc)``,
+    ``acc`` of ``acc_shape``, reset on the slot's first block; ``attend(b,
+    pos0, blocks, length, carry) -> carry`` with ``blocks`` the sources'
+    ``[T, lanes]`` rows of positions ``pos0 ..``; and ``emit(b, carry)``
+    on its last block. The output is zeroed first: a slot of length 0
+    emits zeros."""
+    B, M = tbl_ref.shape
+    page, T = srcs[0].shape[2], buf.shape[2]
     ppb = T // page
-    M = tbl_ref.shape[1]
-    K = KD // head_dim
     layer = layer_ref[0]
-    rows = _block_rows(G, K, head_dim)
     o_ref[...] = jnp.zeros_like(o_ref)
 
-    # the work list, on the scalar core: (slot, block) of every block
-    # that holds a live position, slot by slot, block by block
     def add_slot(b, n):
         def add(j, n):
             slot_ref[n] = b
@@ -315,7 +334,8 @@ def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def copies(item, half):
         """Per page of work item ``item``: whether it holds a live
-        position, and its K and V copies into buffer ``half``."""
+        position, and its copies, one from each source, into buffer
+        ``half``."""
         b = slot_ref[item]
         out = []
         for i in range(ppb):
@@ -325,7 +345,7 @@ def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
                 pltpu.make_async_copy(src.at[layer, pid],
                                       buf.at[c, half, pl.ds(i * page, page)],
                                       sem.at[half])
-                for c, src in enumerate((k_hbm, v_hbm))]))
+                for c, src in enumerate(srcs)]))
         return out
 
     def start(item, half):
@@ -358,21 +378,66 @@ def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
         length = len_ref[b]
         fresh = blk == 0
         carry = tuple(jnp.where(fresh, x0, x) for x0, x in zip(init, carry))
-        m, l, acc = _attend_block(q_ref, b, buf[0, half], buf[1, half],
-                                  blk * T, length, carry, rows,
-                                  sm_scale=sm_scale)
+        carry = attend(b, blk * T, [buf[c, half] for c in range(len(srcs))],
+                       length, carry)
 
         @pl.when((blk + 1) * T >= length)
         def _():
-            _emit_rows(o_ref, b, acc, l, rows)
+            emit(b, carry)
 
-        return m, l, acc
+        return carry
 
-    H = G * K
+    H = acc_shape[0]
     init = (jnp.full((H, 1), NEG_INF, jnp.float32),
             jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, KD), jnp.float32))
+            jnp.zeros(acc_shape, jnp.float32))
     jax.lax.fori_loop(0, n_items, body, init)
+
+
+def _walk_spec(q_shape, out_shape, srcs, M: int):
+    """The grid spec of a kernel over ``_walk_live_blocks``: one grid
+    step, the queries and the output whole in VMEM, the slabs ``srcs``
+    left in HBM; as scratch their double buffer of ``paged_block_rows``
+    positions, its DMA semaphores and the work list, with room for every
+    block of every slot's ``M`` pages."""
+    _, _, page, lanes = srcs[0].shape
+    T = paged_block_rows(page, lanes)
+    n_max = q_shape[0] * -(-M * page // T)
+
+    def whole(*_):
+        return (0,) * len(q_shape)
+
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(1,),
+        in_specs=[pl.BlockSpec(q_shape, whole)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(srcs),
+        out_specs=pl.BlockSpec(out_shape, whole),
+        scratch_shapes=[pltpu.VMEM((len(srcs), 2, T, lanes), srcs[0].dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((n_max,), jnp.int32),
+                        pltpu.SMEM((n_max,), jnp.int32)])
+
+
+def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  buf, sem, slot_ref, blk_ref, *, head_dim: int,
+                  sm_scale: float):
+    G, KD = q_ref.shape[1], q_ref.shape[3]
+    K = KD // head_dim
+    rows = _block_rows(G, K, head_dim)
+
+    def attend(b, pos0, blocks, length, carry):
+        keys, values = blocks
+        return _attend_block(q_ref, b, keys, values, pos0, length, carry,
+                             rows, sm_scale=sm_scale)
+
+    def emit(b, carry):
+        _, l, acc = carry
+        _emit_rows(o_ref, b, acc, l, rows)
+
+    _walk_live_blocks(layer_ref, len_ref, tbl_ref, (k_hbm, v_hbm), o_ref,
+                      buf, sem, slot_ref, blk_ref, acc_shape=(G * K, KD),
+                      attend=attend, emit=emit)
 
 
 def _paged_grid_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_ref, v_ref,
@@ -432,7 +497,6 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # group-major queries on the slab's lanes: qg[b, g, 0, kv * D + d]
     # is query head kv * G + g
     qg = jnp.swapaxes(q.reshape(B, K, G, D), 1, 2).reshape(B, G, 1, KD)
-    whole = pl.BlockSpec((B, G, 1, KD), lambda *_: (0, 0, 0, 0))
     if page % 8:
         def kv_map(b, j, lyr, lens, tbl):
             # an empty row reads the null page, not its dead table entry
@@ -455,20 +519,9 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                             pltpu.VMEM((H, 1), jnp.float32),
                             pltpu.VMEM((H, KD), jnp.float32)])
     else:
-        T = paged_block_rows(page, K, D)
-        n_max = B * -(-M * page // T)
-        kernel = functools.partial(_paged_kernel, page=page, head_dim=D,
+        kernel = functools.partial(_paged_kernel, head_dim=D,
                                    sm_scale=scale)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(1,),
-            in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=whole,
-            scratch_shapes=[pltpu.VMEM((2, 2, T, KD), k_pages.dtype),
-                            pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.SMEM((n_max,), jnp.int32),
-                            pltpu.SMEM((n_max,), jnp.int32)])
+        grid_spec = _walk_spec(qg.shape, qg.shape, (k_pages, v_pages), M)
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -480,117 +533,35 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return jnp.swapaxes(o.reshape(B, G, K, D), 1, 2).reshape(B, H, D)
 
 
-#: Positions of one slot that one step of ``latent_decode_paged``'s loop
-#: attends: ``LATENT_BLOCK // page`` pages, copied into VMEM together.
-LATENT_BLOCK = 512
-
-
 def check_latent_page_size(page_size: int) -> None:
     """Raise ValueError unless ``latent_decode_paged`` can take this
     page: a multiple of 16 rows (a bf16 tile, so that each page's copy
     lands on whole tiles of the block buffer) that divides
-    ``LATENT_BLOCK``."""
+    ``PAGED_BLOCK``, 512."""
     if not (page_size >= 16 and page_size % 16 == 0
-            and LATENT_BLOCK % page_size == 0):
+            and PAGED_BLOCK % page_size == 0):
         raise ValueError(
             f"page_size={page_size} is not a legal block of "
             f"latent_decode_paged: use a multiple of 16 that divides "
-            f"{LATENT_BLOCK}")
+            f"{PAGED_BLOCK}")
 
 
-def _latent_kernel(layer_ref, len_ref, tbl_ref, slot_ref, blk_ref, n_ref,
-                   q_ref, slab_ref, o_ref, buf, sem, *, page: int,
-                   v_dim: int, sm_scale: float):
-    T = buf.shape[1]
-    ppb = T // page
-    M = tbl_ref.shape[1]
-    H = q_ref.shape[1]
-    layer = layer_ref[0]
-    n_items = n_ref[0]
-    o_ref[...] = jnp.zeros_like(o_ref)
-
-    def copies(item, half):
-        """The page copies of work item ``item`` into buffer ``half``,
-        each with whether its page holds a live position."""
-        b = slot_ref[item]
-        out = []
-        for i in range(ppb):
-            j = blk_ref[item] * ppb + i
-            pid = tbl_ref[b, jnp.minimum(j, M - 1)]
-            cp = pltpu.make_async_copy(slab_ref.at[layer, pid],
-                                       buf.at[half, pl.ds(i * page, page)],
-                                       sem.at[half])
-            out.append((j * page < len_ref[b], cp))
-        return out
-
-    def start(item, half):
-        for live, cp in copies(item, half):
-            pl.when(live)(cp.start)
-
-    def wait(item, half):
-        for live, cp in copies(item, half):
-            pl.when(live)(cp.wait)
-
-    @pl.when(n_items > 0)
-    def _():
-        start(0, 0)
-
-    def body(item, carry):
-        m, l, acc = carry
-        half = item % 2
-
-        @pl.when(item + 1 < n_items)
-        def _():
-            start(item + 1, 1 - half)
-
-        wait(item, half)
-        b, blk = slot_ref[item], blk_ref[item]
-        length = len_ref[b]
-        first = blk == 0
-        m = jnp.where(first, NEG_INF, m)
-        l = jnp.where(first, 0.0, l)
-        acc = jnp.where(first, 0.0, acc)
-        rows = buf[half]                                   # [T, C]
+def _latent_kernel(layer_ref, len_ref, tbl_ref, q_ref, slab_hbm, o_ref, buf,
+                   sem, slot_ref, blk_ref, *, v_dim: int, sm_scale: float):
+    def attend(b, pos0, blocks, length, carry):
+        rows, = blocks                                     # [T, C]
         s = _dot_f32(q_ref[b], rows,
                      (((1,), (1,)), ((), ()))) * sm_scale    # [H, T]
-        pos = blk * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)                             # [H, T]
-        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        # rows past the length hold whatever the buffer held: zero them,
-        # since a zero weight times a stale NaN is still NaN
-        col = blk * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
-        v = jnp.where(col < length, rows[:, :v_dim], 0)
-        acc = acc * alpha + _mm_f32(p, v, (((1,), (0,)), ((), ())))
+        return _fold_block(carry, s, rows[:, :v_dim], pos0, length)
 
-        @pl.when((blk + 1) * T >= length)
-        def _():
-            o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    def emit(b, carry):
+        _, l, acc = carry
+        o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
-        return m_new, l, acc
-
-    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
-            jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, v_dim), jnp.float32))
-    jax.lax.fori_loop(0, n_items, body, init)
-
-
-def _latent_work_items(lengths: jax.Array, n_blocks: int):
-    """The flattened (slot, block) list of a batch: ``(slots, blocks,
-    count)``, each list ``B * n_blocks`` long with the first ``count``
-    entries live, slot by slot, block by block."""
-    B = lengths.shape[0]
-    nb = (lengths + LATENT_BLOCK - 1) // LATENT_BLOCK
-    ends = jnp.cumsum(nb)
-    i = jnp.arange(B * n_blocks, dtype=jnp.int32)
-    # one compare and sum, not a search: a search is a loop of small ops
-    # on the device, paid in every layer
-    slots = jnp.minimum(jnp.sum(ends[None, :] <= i[:, None], axis=1,
-                                dtype=jnp.int32), B - 1)
-    blocks = i - jnp.take(ends - nb, slots)
-    return slots, blocks.astype(jnp.int32), ends[-1:].astype(jnp.int32)
+    _walk_live_blocks(layer_ref, len_ref, tbl_ref, (slab_hbm,), o_ref, buf,
+                      sem, slot_ref, blk_ref,
+                      acc_shape=(q_ref.shape[1], v_dim), attend=attend,
+                      emit=emit)
 
 
 def latent_decode_paged(q: jax.Array, kv_pages: jax.Array, layer,
@@ -608,33 +579,16 @@ def latent_decode_paged(q: jax.Array, kv_pages: jax.Array, layer,
     MXU product for all heads of a block), f32 online softmax; rows with
     ``lengths == 0`` emit zeros. Only live pages are copied.
     """
-    B, H, C = q.shape
-    page = kv_pages.shape[2]
-    check_latent_page_size(page)
-    M = page_table.shape[1]
-    n_blocks = -(-M * page // LATENT_BLOCK)
-    lengths = lengths.astype(jnp.int32)
-    slots, blocks, count = _latent_work_items(lengths, n_blocks)
-
-    def whole(*_):
-        return (0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((B, H, C), whole),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((B, H, v_dim), whole),
-        scratch_shapes=[pltpu.VMEM((2, LATENT_BLOCK, C), kv_pages.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    kernel = functools.partial(_latent_kernel, page=page, v_dim=v_dim,
+    B, H, _ = q.shape
+    check_latent_page_size(kv_pages.shape[2])
+    out = (B, H, v_dim)
+    kernel = functools.partial(_latent_kernel, v_dim=v_dim,
                                sm_scale=sm_scale)
     return pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, v_dim), q.dtype),
+        grid_spec=_walk_spec(q.shape, out, (kv_pages,), page_table.shape[1]),
+        out_shape=jax.ShapeDtypeStruct(out, q.dtype),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="latent_decode_paged",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths,
-      page_table.astype(jnp.int32), slots, blocks, count, q, kv_pages)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
+      page_table.astype(jnp.int32), q, kv_pages)

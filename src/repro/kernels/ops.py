@@ -18,17 +18,16 @@ from typing import Optional
 import jax
 
 from . import ref
-from .decode_attention import (LATENT_BLOCK, check_latent_page_size,
-                               check_page_size, flash_decode,
-                               flash_decode_paged, latent_decode_paged,
-                               paged_block_rows)
+from .decode_attention import (check_latent_page_size, check_page_size,
+                               flash_decode, flash_decode_paged,
+                               latent_decode_paged, paged_block_rows)
 from .flash_attention import flash_attention
 from .mamba2_scan import mamba2_chunked
 from .rwkv6_scan import rwkv6_chunked
 
 __all__ = ["attention", "decode_attention", "paged_decode_attention",
            "latent_decode_attention", "check_page_size",
-           "check_latent_page_size", "paged_block_rows", "LATENT_BLOCK",
+           "check_latent_page_size", "paged_block_rows",
            "rwkv6_scan", "mamba2_scan",
            "pallas_mode"]
 

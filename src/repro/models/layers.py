@@ -405,27 +405,24 @@ def apply_mla(p: Params, cfg: ModelConfig, x: jax.Array,
 
 def apply_mla_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
                            kv_pages: jax.Array, layer,
-                           page_table: jax.Array, lengths: jax.Array,
-                           slot_mask: jax.Array
+                           page_table: jax.Array, lengths: jax.Array, rows
                            ) -> Tuple[jax.Array, jax.Array]:
     """One-token latent decode of layer ``layer`` against the latent slab.
 
     x: [B, 1, D]; kv_pages: [L, P, page, lanes], the whole slab, page
-    0 the null page; the rest as in :func:`apply_attention_decode_paged`:
-    the new row is written at ``lengths`` (a masked slot's to the null
-    page) by one scatter into the slab as passed in, then
+    0 the null page; the rest as in :func:`apply_attention_decode_paged`
+    (``rows`` is ``paged_decode_rows`` of the step): the new row is
+    written at ``lengths`` (a masked slot's to the null page) by one
+    scatter into the slab as passed in, then
     ``ops.latent_decode_attention`` reads each slot's live pages once,
     as keys (all lanes) and values (the latent's), and ``W_UV`` and
     ``wo`` take the heads' outputs out of the latent."""
-    B = x.shape[0]
-    page, lanes = kv_pages.shape[2:]
+    pid, off, att_len = rows
+    lanes = kv_pages.shape[3]
     q, row = mla_project(p, cfg, x, lengths[:, None])
     q, row = _pad_lanes(q, lanes), _pad_lanes(row, lanes)
-    pid = page_table[jnp.arange(B), lengths // page]
-    pid = jnp.where(slot_mask, pid, 0)
-    kv_pages = kv_pages.at[layer, pid, lengths % page].set(
+    kv_pages = kv_pages.at[layer, pid, off].set(
         row[:, 0].astype(kv_pages.dtype))
-    att_len = jnp.where(slot_mask, lengths + 1, 0)
     o = ops.latent_decode_attention(q[:, 0], kv_pages, layer, page_table,
                                     att_len, sm_scale=mla_scale(cfg),
                                     v_dim=cfg.kv_lora_rank)

@@ -473,11 +473,10 @@ class Model:
 
     def decode_block_rows(self, page_size: int) -> int:
         """Positions of one slot that one step of the paged decode
-        kernel's walk over live blocks attends."""
-        if self.cfg.is_mla:
-            return ops.LATENT_BLOCK
-        return ops.paged_block_rows(page_size, self.cfg.num_kv_heads,
-                                    self.cfg.hd)
+        kernel's walk over live blocks attends, from the slab's lanes."""
+        slab = jax.tree.leaves(jax.eval_shape(
+            lambda: self.init_paged_cache(1, page_size)))[0]
+        return ops.paged_block_rows(page_size, slab.shape[-1])
 
     def init_paged_cache(self, num_pages: int, page_size: int
                          ) -> Dict[str, jax.Array]:
@@ -561,17 +560,16 @@ class Model:
         self._check_paged()
         cfg = self.cfg
         x = L.embed(params["embed"], cfg, tokens[:, None])
+        rows = L.paged_decode_rows(page_tables, lengths, slot_mask,
+                                   jax.tree.leaves(pages)[0].shape[2])
 
         if cfg.is_mla:
             def attend(p, h, pages, i):
                 a, kv = L.apply_mla_decode_paged(
                     p, cfg, h, pages["kv_pages"], i, page_tables, lengths,
-                    slot_mask)
+                    rows)
                 return a, {"kv_pages": kv}
         else:
-            rows = L.paged_decode_rows(page_tables, lengths, slot_mask,
-                                       pages["k_pages"].shape[2])
-
             def attend(p, h, pages, i):
                 a, kp, vp = L.apply_attention_decode_paged(
                     p, cfg, h, pages["k_pages"], pages["v_pages"], i,

@@ -10,6 +10,7 @@ from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import (flash_decode, flash_decode_paged,
+                                            latent_decode_paged,
                                             paged_block_rows)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba2_scan import mamba2_chunked
@@ -259,7 +260,7 @@ class TestPagedDecode:
         a batch with one live slot: against the oracle, zero-length rows
         exactly zero."""
         D = 32
-        T = paged_block_rows(page, K, D)
+        T = paged_block_rows(page, K * D)
         lens = [T - 1, T, T + 1] if lens == "edges" else [0, 0, T + 1, 0]
         q, kp, vp, table, lengths = (jnp.asarray(x) for x in self._slab(
             len(lens), H, K, D, page, lens))
@@ -273,14 +274,19 @@ class TestPagedDecode:
                 assert not got[b].any()
 
     @pytest.mark.parametrize("dead", ["nan", "outside"])
-    @pytest.mark.parametrize("page", [16, 128, 7])
-    def test_dead_pages_are_never_read(self, page, dead):
+    @pytest.mark.parametrize("kernel,page", [
+        ("gqa", 16), ("gqa", 128), ("gqa", 7),
+        ("latent", 16), ("latent", 128),
+    ], ids=["16", "128", "7", "latent-16", "latent-128"])
+    def test_dead_pages_are_never_read(self, kernel, page, dead):
         """Every page but the slots' live ones holds NaN; the table's dead
         entries name such pages (``nan``) or pages past the slab, which
         the interpreter refuses to copy (``outside``). The output stays
-        finite and matches the oracle on the clean slab."""
+        finite and matches the oracle on the clean slab. Both kernels of
+        the walk: ``flash_decode_paged`` over K and V, and
+        ``latent_decode_paged`` over the K slab read as latent rows."""
         H, K, D = 8, 4, 32
-        T = paged_block_rows(page, K, D)
+        T = paged_block_rows(page, K * D)
         lens = [0, 3, T + page + 1, 2 * page - 1]
         q, kp, vp, table, lengths = self._slab(len(lens), H, K, D, page,
                                                lens, seed=1)
@@ -293,12 +299,22 @@ class TestPagedDecode:
         if dead == "outside":
             for b, n in enumerate(n_live):
                 tbl[b, n:] = kp.shape[1] + 1000
-        got = np.asarray(flash_decode_paged(
-            jnp.asarray(q), jnp.asarray(kp_nan), jnp.asarray(vp_nan), 1,
-            jnp.asarray(tbl), jnp.asarray(lengths), interpret=True))
-        want = np.asarray(ref.paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), 1,
-            jnp.asarray(table), jnp.asarray(lengths)))
+        if kernel == "gqa":
+            got = np.asarray(flash_decode_paged(
+                jnp.asarray(q), jnp.asarray(kp_nan), jnp.asarray(vp_nan), 1,
+                jnp.asarray(tbl), jnp.asarray(lengths), interpret=True))
+            want = np.asarray(ref.paged_decode_attention(
+                jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), 1,
+                jnp.asarray(table), jnp.asarray(lengths)))
+        else:
+            # every head's query on all K * D lanes; values the first 96
+            def latent(fn, slab, t, **kw):
+                return np.asarray(fn(
+                    jnp.asarray(np.tile(q, K)), jnp.asarray(slab), 1,
+                    jnp.asarray(t), jnp.asarray(lengths),
+                    sm_scale=(K * D) ** -0.5, v_dim=96, **kw))
+            got = latent(latent_decode_paged, kp_nan, tbl, interpret=True)
+            want = latent(ref.latent_decode_attention, kp, table)
         assert np.isfinite(got).all()
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
@@ -310,9 +326,10 @@ class TestPagedDecode:
         (1024, 16, 64, 1024),     # past the double buffer's budget
         (16, 20, 128, 128),       # qwen1.5-4b: 2,560 lanes
         (16, 8, 112, 512),        # kimi-k2: 896 lanes
+        (16, 1, 640, 512),        # moonlight-16b-a3b: latent rows of 640
     ])
     def test_block_rows_follow_the_shape(self, page, kv_heads, hd, rows):
-        T = paged_block_rows(page, kv_heads, hd)
+        T = paged_block_rows(page, kv_heads * hd)
         assert T == rows and T % page == 0
 
     def test_gather_round_trip(self):
@@ -407,8 +424,8 @@ class TestLatentDecode:
     ``ref.latent_decode_attention``: 16 heads reading one latent row per
     position (G = 16), rows zero-padded to whole 128-lane tiles as the
     model stores them, pages scattered over a slab of two layers, ragged
-    lengths with a length-0 row and lengths across the kernel's blocks
-    of ``LATENT_BLOCK`` positions."""
+    lengths with a length-0 row and lengths across the walk's blocks of
+    512 positions (``paged_block_rows``)."""
 
     H, R, ROPE, LANES, PAGE = 16, 96, 32, 256, 16
 

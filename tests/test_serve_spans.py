@@ -114,7 +114,8 @@ def served(model_params, tmp_path_factory):
     eng._store = _CmdSpans(eng._store)
     calls, blocks = [], []
     decode = eng._decode
-    T = paged_block_rows(eng.page_size, SMOKE.num_kv_heads, SMOKE.hd)
+    T = paged_block_rows(eng.page_size,
+                         SMOKE.num_kv_heads * SMOKE.hd)
 
     def recorded(params, pages, tokens, tables, lengths, mask):
         live = np.asarray(mask)

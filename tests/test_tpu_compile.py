@@ -12,8 +12,9 @@ those sizes and check that the page slab stays whole: no temporaries
 and no data move as large as one layer of it. The same holds for
 moonlight-16b-a3b's latent kernel ``latent_decode_paged`` and its two
 steps, at the benchmark cell's sizes (24 slots of 7,168 positions, 8 of
-64 experts held). Nothing runs, so nothing about results or speed is
-checked here.
+64 experts held), whose decode step hands the latent kernel the same
+scalar operands as ``flash_decode_paged``. Nothing runs, so nothing
+about results or speed is checked here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -245,3 +246,33 @@ def test_engine_step_keeps_the_latent_slab_whole(one_chip, monkeypatch,
     text = compiled.as_text()
     assert ("latent_decode_paged" in text) == (step == "decode")
     assert _large_moves(text, layer) == []
+
+
+_KERNEL_OPERANDS = re.compile(
+    r"^\s*%latent_decode_paged\S* = .* custom-call\(.*"
+    r"operand_layout_constraints=\{((?:\w+\[[\d,]*\]\{[^}]*\}(?:, )?)+)\}",
+    re.M)
+
+
+def test_latent_decode_takes_the_walkers_operands(one_chip, monkeypatch):
+    """In Moonlight's compiled decode step, every ``latent_decode_paged``
+    call takes the scalar operands ``flash_decode_paged`` takes, the
+    layer, the lengths and the table (``s32[1]``, ``s32[slots]``,
+    ``s32[slots, M]``), and no other: the kernel builds its (slot, block)
+    work list itself, so no cumulative sum over the lengths (a
+    ``reduce-window``) runs in the step."""
+    monkeypatch.setattr(ops, "pallas_mode", lambda: "tpu")
+    model = build_model(MOONLIGHT.replace(experts_held=8))
+    compiled, _ = _engine_step(model, one_chip, "decode", ML_SLOTS,
+                               ML_MAX_LEN)
+    text = compiled.as_text()
+    calls = _KERNEL_OPERANDS.findall(text)
+    assert calls
+    M = ML_MAX_LEN // PAGE
+    for operands in calls:
+        scalars = [(dt, dims) for dt, dims in
+                   re.findall(r"(\w+)\[([\d,]*)\]", operands)
+                   if dt.startswith(("s", "u"))]
+        assert scalars == [("s32", "1"), ("s32", str(ML_SLOTS)),
+                           ("s32", f"{ML_SLOTS},{M}")], operands
+    assert "reduce-window" not in text
