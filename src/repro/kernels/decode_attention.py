@@ -23,10 +23,10 @@ valid block, so blocks past the length are neither computed (``pl.when``)
 nor fetched again (an unchanged block index skips the copy). Rows with
 ``lengths == 0`` emit zeros: the accumulator never runs.
 
-The two paged kernels read the serving slab ``[L, P, page, lanes]``
+The four paged kernels read the serving slab ``[L, P, page, lanes]``
 through a per-sequence **page table**, whole: it stays in HBM, and the
 layer's index, the lengths and the table arrive by scalar prefetch.
-Both run one walk (``_walk_live_blocks``) whose time follows the live
+All run one walk (``_walk_live_blocks``) whose time follows the live
 positions, not slots x max_len: one grid step first builds, on the
 scalar core, the list of (slot, block) pairs whose block of ``T``
 positions (``paged_block_rows``) holds a live position, then walks it.
@@ -34,7 +34,7 @@ Each block's live pages are copied from HBM into a VMEM double buffer
 by async copies started one block ahead; a page at or past
 ``ceil(length / page)`` is never copied. The online-softmax state is
 reset on a slot's first block and emitted on its last. The kernels
-differ only in how they score a block:
+differ only in how they score a block. Two decode one token a slot:
 
 - :func:`flash_decode_paged` (GQA/MHA) reads K and V from two slabs
   ``[L, P, page, K * hd]``. The queries sit block-diagonally on the
@@ -49,6 +49,19 @@ differ only in how they score a block:
   ``[L, P, page, C]`` of latent rows, each the key of every head (all
   ``C`` lanes) and its value (the first ``v_dim``): one product scores
   all heads of a block.
+
+Two attend one request's prompt chunk of ``C`` rows at positions
+``start ..`` (a batch of one, length ``start + n_valid``, ``start`` a
+fourth scalar operand), so a chunk reads the blocks up to its own end,
+not the request's ``max_len`` window. Their state holds every query row
+in VMEM scratch; blocks wholly before ``start`` are attended unmasked,
+the frontier block under ``col <= start + row``:
+
+- :func:`paged_prefill_attention` (GQA/MHA) scores each 128-lane tile of
+  the K slab (``prefill_lane_groups`` kv heads) against only those
+  heads' query rows, placed block-diagonally on the tile's lanes;
+- :func:`latent_prefill_attention` scores every head's rows against
+  the latent rows, ``PREFILL_ROWS`` rows a product.
 """
 
 from __future__ import annotations
@@ -299,9 +312,9 @@ def _emit_rows(o_ref, b, acc, l, rows):
 
 
 def _walk_live_blocks(layer_ref, len_ref, tbl_ref, srcs, o_ref, buf, sem,
-                      slot_ref, blk_ref, *, acc_shape, attend, emit):
-    """The walk of both paged decode kernels over the live blocks of a
-    batch, in one grid step.
+                      slot_ref, blk_ref, *, init, attend, emit):
+    """The walk of the paged kernels over the live blocks of a batch, in
+    one grid step.
 
     srcs: the HBM slabs ``[L, P, page, lanes]`` read at layer
     ``layer_ref[0]`` through the table (``(k, v)``, or the latent slab
@@ -311,12 +324,13 @@ def _walk_live_blocks(layer_ref, len_ref, tbl_ref, srcs, o_ref, buf, sem,
     positions holds a live position, slot by slot, block by block. Then
     for each pair: the block's live pages copied into one half of the
     buffer (started one pair ahead; a page at or past ``ceil(length /
-    page)`` is never copied); the online-softmax state ``(m, l, acc)``,
-    ``acc`` of ``acc_shape``, reset on the slot's first block; ``attend(b,
-    pos0, blocks, length, carry) -> carry`` with ``blocks`` the sources'
-    ``[T, lanes]`` rows of positions ``pos0 ..``; and ``emit(b, carry)``
-    on its last block. The output is zeroed first: a slot of length 0
-    emits zeros."""
+    page)`` is never copied); the loop's carry reset to ``init()`` on
+    the slot's first block (the decode kernels carry their online-softmax
+    state ``(m, l, acc)``; a kernel that keeps it in scratch carries
+    ``()``); ``attend(b, pos0, blocks, length, carry) -> carry`` with
+    ``blocks`` the refs of the sources' ``[T, lanes]`` rows of positions
+    ``pos0 ..``; and ``emit(b, carry)`` on its last block. The output is
+    zeroed first: a slot of length 0 emits zeros."""
     B, M = tbl_ref.shape
     page, T = srcs[0].shape[2], buf.shape[2]
     ppb = T // page
@@ -378,8 +392,9 @@ def _walk_live_blocks(layer_ref, len_ref, tbl_ref, srcs, o_ref, buf, sem,
         length = len_ref[b]
         fresh = blk == 0
         carry = tuple(jnp.where(fresh, x0, x) for x0, x in zip(init, carry))
-        carry = attend(b, blk * T, [buf[c, half] for c in range(len(srcs))],
-                       length, carry)
+        carry = attend(b, blk * T,
+                       [buf.at[c, half] for c in range(len(srcs))], length,
+                       carry)
 
         @pl.when((blk + 1) * T >= length)
         def _():
@@ -387,28 +402,38 @@ def _walk_live_blocks(layer_ref, len_ref, tbl_ref, srcs, o_ref, buf, sem,
 
         return carry
 
-    H = acc_shape[0]
-    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
-            jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros(acc_shape, jnp.float32))
+    init = init()
     jax.lax.fori_loop(0, n_items, body, init)
 
 
-def _walk_spec(q_shape, out_shape, srcs, M: int):
+def _softmax_init(acc_shape):
+    """A fresh online-softmax state ``(m, l, acc)`` of ``acc_shape[0]``
+    query rows."""
+    H = acc_shape[0]
+    return (jnp.full((H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros(acc_shape, jnp.float32))
+
+
+def _walk_spec(q_shape, out_shape, srcs, table_shape, n_scalars: int = 3,
+               scratch=()):
     """The grid spec of a kernel over ``_walk_live_blocks``: one grid
     step, the queries and the output whole in VMEM, the slabs ``srcs``
-    left in HBM; as scratch their double buffer of ``paged_block_rows``
-    positions, its DMA semaphores and the work list, with room for every
-    block of every slot's ``M`` pages."""
+    left in HBM, ``n_scalars`` scalar operands (the layer, the lengths
+    and the ``[B, M]`` table first); as scratch their double buffer of
+    ``paged_block_rows`` positions, its DMA semaphores and the work list,
+    with room for every block of every slot's ``M`` pages, then
+    ``scratch``."""
     _, _, page, lanes = srcs[0].shape
     T = paged_block_rows(page, lanes)
-    n_max = q_shape[0] * -(-M * page // T)
+    B, M = table_shape
+    n_max = B * -(-M * page // T)
 
     def whole(*_):
         return (0,) * len(q_shape)
 
     return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=n_scalars,
         grid=(1,),
         in_specs=[pl.BlockSpec(q_shape, whole)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(srcs),
@@ -416,7 +441,7 @@ def _walk_spec(q_shape, out_shape, srcs, M: int):
         scratch_shapes=[pltpu.VMEM((len(srcs), 2, T, lanes), srcs[0].dtype),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SMEM((n_max,), jnp.int32),
-                        pltpu.SMEM((n_max,), jnp.int32)])
+                        pltpu.SMEM((n_max,), jnp.int32), *scratch])
 
 
 def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -428,16 +453,18 @@ def _paged_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def attend(b, pos0, blocks, length, carry):
         keys, values = blocks
-        return _attend_block(q_ref, b, keys, values, pos0, length, carry,
-                             rows, sm_scale=sm_scale)
+        return _attend_block(q_ref, b, keys[...], values[...], pos0, length,
+                             carry, rows, sm_scale=sm_scale)
 
     def emit(b, carry):
         _, l, acc = carry
         _emit_rows(o_ref, b, acc, l, rows)
 
     _walk_live_blocks(layer_ref, len_ref, tbl_ref, (k_hbm, v_hbm), o_ref,
-                      buf, sem, slot_ref, blk_ref, acc_shape=(G * K, KD),
-                      attend=attend, emit=emit)
+                      buf, sem, slot_ref, blk_ref,
+                      init=lambda: _softmax_init((G * K, KD)),
+                      attend=attend,
+                      emit=emit)
 
 
 def _paged_grid_kernel(layer_ref, len_ref, tbl_ref, q_ref, k_ref, v_ref,
@@ -521,7 +548,8 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     else:
         kernel = functools.partial(_paged_kernel, head_dim=D,
                                    sm_scale=scale)
-        grid_spec = _walk_spec(qg.shape, qg.shape, (k_pages, v_pages), M)
+        grid_spec = _walk_spec(qg.shape, qg.shape, (k_pages, v_pages),
+                               page_table.shape)
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -549,7 +577,7 @@ def check_latent_page_size(page_size: int) -> None:
 def _latent_kernel(layer_ref, len_ref, tbl_ref, q_ref, slab_hbm, o_ref, buf,
                    sem, slot_ref, blk_ref, *, v_dim: int, sm_scale: float):
     def attend(b, pos0, blocks, length, carry):
-        rows, = blocks                                     # [T, C]
+        rows = blocks[0][...]                              # [T, C]
         s = _dot_f32(q_ref[b], rows,
                      (((1,), (1,)), ((), ()))) * sm_scale    # [H, T]
         return _fold_block(carry, s, rows[:, :v_dim], pos0, length)
@@ -560,8 +588,8 @@ def _latent_kernel(layer_ref, len_ref, tbl_ref, q_ref, slab_hbm, o_ref, buf,
 
     _walk_live_blocks(layer_ref, len_ref, tbl_ref, (slab_hbm,), o_ref, buf,
                       sem, slot_ref, blk_ref,
-                      acc_shape=(q_ref.shape[1], v_dim), attend=attend,
-                      emit=emit)
+                      init=lambda: _softmax_init((q_ref.shape[1], v_dim)),
+                      attend=attend, emit=emit)
 
 
 def latent_decode_paged(q: jax.Array, kv_pages: jax.Array, layer,
@@ -586,9 +614,240 @@ def latent_decode_paged(q: jax.Array, kv_pages: jax.Array, layer,
                                sm_scale=sm_scale)
     return pl.pallas_call(
         kernel,
-        grid_spec=_walk_spec(q.shape, out, (kv_pages,), page_table.shape[1]),
+        grid_spec=_walk_spec(q.shape, out, (kv_pages,), page_table.shape),
         out_shape=jax.ShapeDtypeStruct(out, q.dtype),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="latent_decode_paged",
     )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
       page_table.astype(jnp.int32), q, kv_pages)
+
+
+#: Most query rows one product of the paged prefill kernels scores
+#: against a block (``_prefill_tiles``).
+PREFILL_ROWS = 512
+#: Scoped VMEM of the paged prefill kernels: their online-softmax state
+#: holds every query row of the chunk (about 6 MB at Moonlight-16B-A3B's
+#: 2,048 rows of 512 value lanes), beside the queries, the output and the
+#: block buffer.
+PREFILL_VMEM_BYTES = 64 << 20
+
+
+def _prefill_tiles(rows: int, chunk: int) -> int:
+    """Rows of one scoring product: a whole number of chunks (so row
+    ``r`` of a tile is position ``r mod chunk``), at most
+    ``PREFILL_ROWS`` where the chunk allows, dividing ``rows``."""
+    n = rows // chunk
+    d = max([d for d in range(1, n + 1)
+             if n % d == 0 and d * chunk <= PREFILL_ROWS] or [1])
+    return d * chunk
+
+
+def _prefill_kernel(layer_ref, len_ref, tbl_ref, start_ref, q_ref, *refs,
+                    n_src: int, chunk: int, v_lanes: int, head_dim: int,
+                    sm_scale: float):
+    """Attention of one request's prompt chunk over ``_walk_live_blocks``
+    (B = 1, length ``start + n_valid``).
+
+    q_ref: ``[n_grp, R, W]``, group ``grp``'s query rows (row ``r`` at
+    position ``r mod chunk``) over lanes ``grp * W ..`` of the keys;
+    ``o_ref`` the output ``[n_grp, R // kpg, v_lanes]``. ``head_dim``
+    is 0 for latent rows (one group of every head, values the first
+    ``v_lanes`` lanes of the keys, nothing to fold); otherwise the K and
+    V slabs, ``kpg = W // head_dim`` kv heads a group placed
+    block-diagonally, whose ``kpg`` row blocks fold into one at emit.
+    The state ``(m, l, acc)`` of every row lives in scratch; blocks before
+    ``start`` are attended whole, the frontier block (a position at or
+    past ``start``) under ``col <= min(start + row, length - 1)``."""
+    srcs, o_ref = refs[:n_src], refs[n_src]
+    buf, sem, slot_ref, blk_ref, m_ref, l_ref, acc_ref = refs[n_src + 1:]
+    n_grp, R, W = q_ref.shape
+    rt = _prefill_tiles(R, chunk)
+    T = buf.shape[2]
+    start = start_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(grp, r0, keys, values, lim):
+        rows = slice(r0, r0 + rt)
+        q = q_ref[grp, rows]
+        dt = jnp.promote_types(q.dtype, keys.dtype)
+        s = _dot_f32(q.astype(dt), keys.astype(dt),
+                     (((1,), (1,)), ((), ()))) * sm_scale    # [rt, T]
+        if lim is not None:
+            s = jnp.where(lim[0] + jax.lax.broadcasted_iota(
+                jnp.int32, (1, T), 1) <= lim[1], s, NEG_INF)
+        m_prev, l_prev = m_ref[grp, rows], l_ref[grp, rows]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[grp, rows] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[grp, rows] = acc_ref[grp, rows] * alpha + _dot_f32(
+            p.astype(values.dtype), values, (((1,), (0,)), ((), ())))
+        m_ref[grp, rows] = m_new
+
+    def block(pos0, blocks, length, frontier):
+        lim = None
+        if frontier:
+            # past the length the buffer holds whatever it held: those
+            # values are zeroed, since a zero weight times a stale NaN
+            # is NaN
+            col = pos0 + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)
+            row = jax.lax.broadcasted_iota(jnp.int32, (rt, 1), 0)
+            pos = row - sum((row >= i * chunk).astype(jnp.int32) * chunk
+                            for i in range(1, rt // chunk))
+            lim = (pos0, jnp.minimum(start + pos, length - 1))
+        for grp in range(n_grp):
+            if head_dim:
+                lanes = slice(grp * W, (grp + 1) * W)
+                keys, values = blocks[0][:, lanes], blocks[1][:, lanes]
+            else:
+                keys, values = blocks[0][...], blocks[0][:, :v_lanes]
+            if frontier:
+                values = jnp.where(col < length, values, 0)
+            for r0 in range(0, R, rt):
+                tile(grp, r0, keys, values, lim)
+
+    def attend(b, pos0, blocks, length, carry):
+        frontier = pos0 + T > start
+
+        @pl.when(frontier)
+        def _():
+            block(pos0, blocks, length, True)
+
+        @pl.when(jnp.logical_not(frontier))
+        def _():
+            block(pos0, blocks, length, False)
+
+        return carry
+
+    def emit(b, carry):
+        for grp in range(n_grp):
+            out = acc_ref[grp] / jnp.maximum(l_ref[grp], 1e-30)   # [R, V]
+            if head_dim:
+                # row block j holds kv head j's rows: keep its lanes
+                n = R // (W // head_dim)
+                lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+                out = sum(jnp.where((lane >= j * head_dim)
+                                    & (lane < (j + 1) * head_dim),
+                                    out[j * n:(j + 1) * n], 0.0)
+                          for j in range(W // head_dim))
+            o_ref[grp] = out.astype(o_ref.dtype)
+
+    _walk_live_blocks(layer_ref, len_ref, tbl_ref, srcs, o_ref, buf, sem,
+                      slot_ref, blk_ref, init=tuple, attend=attend, emit=emit)
+
+
+def _prefill_call(name, q, srcs, layer, page_table, start, n_valid, *,
+                  out_rows: int, v_lanes: int, head_dim: int, chunk: int,
+                  sm_scale: float, interpret: bool):
+    """One ``pallas_call`` of ``_prefill_kernel``: ``q`` ``[n_grp, R,
+    W]``, the request's table row ``[M]`` as a batch of one, its length
+    ``start + n_valid`` and ``start`` as scalar operands."""
+    n_grp, R, _ = q.shape
+    out = (n_grp, out_rows, v_lanes)
+    scratch = [pltpu.VMEM((n_grp, R, 1), jnp.float32),
+               pltpu.VMEM((n_grp, R, 1), jnp.float32),
+               pltpu.VMEM((n_grp, R, v_lanes), jnp.float32)]
+    kernel = functools.partial(_prefill_kernel, n_src=len(srcs), chunk=chunk,
+                               v_lanes=v_lanes, head_dim=head_dim,
+                               sm_scale=sm_scale)
+    start = jnp.asarray(start, jnp.int32).reshape(1)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=_walk_spec(q.shape, out, srcs, (1, page_table.shape[0]),
+                             n_scalars=4, scratch=scratch),
+        out_shape=jax.ShapeDtypeStruct(out, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PREFILL_VMEM_BYTES),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name=name,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      start + jnp.asarray(n_valid, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32).reshape(1, -1), start, q, *srcs)
+
+
+def prefill_lane_groups(num_kv_heads: int,
+                        head_dim: int) -> Optional[int]:
+    """Kv heads a lane group of ``paged_prefill_attention`` holds: those
+    of one 128-lane tile (128 / head_dim, or every head where they fill
+    less), or one head of a whole number of tiles. None where the heads
+    do not tile the lanes so (a head of 112, say): their rows would
+    score every lane of the slab, past what VMEM holds."""
+    if head_dim % 128 == 0:
+        return 1
+    if 128 % head_dim == 0:
+        kpg = min(num_kv_heads, 128 // head_dim)
+        if num_kv_heads % kpg == 0:
+            return kpg
+    return None
+
+
+def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, layer, page_table: jax.Array,
+                            start, n_valid, *,
+                            sm_scale: Optional[float] = None,
+                            interpret: bool = False) -> jax.Array:
+    """Chunked prefill attention of one layer for one request, reading
+    only the blocks that hold a position below ``start + n_valid``.
+
+    q: [C, H, D], the chunk's queries at positions ``start ..``; k_pages,
+    v_pages: [L, P, page, K * D] (page a multiple of 8 rows); layer:
+    int32 scalar; page_table: [M], the request's row; start: positions
+    already cached; n_valid: the chunk's real rows, whose K/V are already
+    in the slab -> [C, H, D]. Row ``r < n_valid`` attends positions
+    ``<= start + r``, as ``ref.paged_prefill_attention``; a padding row
+    attends the live positions. The kv heads' lanes go in groups of one
+    128-lane tile (``prefill_lane_groups``, which must not be None); a
+    group's query rows sit
+    block-diagonally on its lanes, so one product scores every head of
+    the group against a block. Scores are exact products of the inputs
+    summed in f32; the softmax is f32 and its weights meet the values in
+    the values' dtype, summed in f32.
+    """
+    C, H, D = q.shape
+    _, _, page, KD = k_pages.shape
+    K = KD // D
+    G = H // K
+    kpg = prefill_lane_groups(K, D)
+    if kpg is None:
+        raise ValueError(f"paged_prefill_attention cannot group {K} kv "
+                         f"heads of {D} into 128-lane tiles")
+    n_grp, W = K // kpg, kpg * D
+    scale = sm_scale if sm_scale is not None else D ** -0.5
+    # qg[n, j, g, c] is query head (n * kpg + j) * G + g at position c
+    qg = q.reshape(C, n_grp, kpg, G, D).transpose(1, 2, 3, 0, 4)
+    q_bd = jnp.einsum("njgcd,jk->njgckd", qg, jnp.eye(kpg, dtype=q.dtype))
+    q_bd = q_bd.reshape(n_grp, kpg * G * C, W)
+    o = _prefill_call("paged_prefill_attention", q_bd, (k_pages, v_pages),
+                      layer, page_table, start, n_valid,
+                      out_rows=G * C, v_lanes=W, head_dim=D, chunk=C,
+                      sm_scale=scale, interpret=interpret)
+    o = o.reshape(n_grp, G, C, kpg, D).transpose(2, 0, 3, 1, 4)
+    return o.reshape(C, H, D)
+
+
+def latent_prefill_attention(q: jax.Array, kv_pages: jax.Array, layer,
+                             page_table: jax.Array, start, n_valid, *,
+                             sm_scale: float, v_dim: int,
+                             interpret: bool = False) -> jax.Array:
+    """Chunked latent (MLA) prefill attention of one layer for one
+    request, reading only the blocks that hold a position below ``start
+    + n_valid``.
+
+    q: [C, H, lanes], the absorbed queries at positions ``start ..``;
+    kv_pages: [L, P, page, lanes], the latent slab; the rest as in
+    :func:`paged_prefill_attention` -> [C, H, v_dim]. All heads' rows
+    share one latent row, so a product scores ``PREFILL_ROWS`` query rows
+    (whole heads) against a block, and the values are the block's first
+    ``v_dim`` lanes. Scores exact products summed in f32, f32 softmax,
+    weights in the slab's dtype against the values, summed in f32.
+    """
+    C, H, lanes = q.shape
+    check_latent_page_size(kv_pages.shape[2])
+    rows = q.transpose(1, 0, 2).reshape(1, H * C, lanes)
+    o = _prefill_call("latent_prefill_attention", rows, (kv_pages,), layer,
+                      page_table, start, n_valid, out_rows=H * C,
+                      v_lanes=v_dim, head_dim=0, chunk=C, sm_scale=sm_scale,
+                      interpret=interpret)
+    return o.reshape(H, C, v_dim).transpose(1, 0, 2)

@@ -20,13 +20,17 @@ import jax
 from . import ref
 from .decode_attention import (check_latent_page_size, check_page_size,
                                flash_decode, flash_decode_paged,
-                               latent_decode_paged, paged_block_rows)
+                               latent_decode_paged, paged_block_rows,
+                               prefill_lane_groups)
+from .decode_attention import latent_prefill_attention as latent_prefill_kernel
+from .decode_attention import paged_prefill_attention as paged_prefill_kernel
 from .flash_attention import flash_attention
 from .mamba2_scan import mamba2_chunked
 from .rwkv6_scan import rwkv6_chunked
 
 __all__ = ["attention", "decode_attention", "paged_decode_attention",
-           "latent_decode_attention", "check_page_size",
+           "latent_decode_attention", "paged_prefill_attention",
+           "latent_prefill_attention", "check_page_size",
            "check_latent_page_size", "paged_block_rows",
            "rwkv6_scan", "mamba2_scan",
            "pallas_mode"]
@@ -92,6 +96,40 @@ def latent_decode_attention(q, kv_pages, layer, page_table, lengths, *,
     return ref.latent_decode_attention(q, kv_pages, layer, page_table,
                                        lengths, sm_scale=sm_scale,
                                        v_dim=v_dim)
+
+
+def paged_prefill_attention(q, k_pages, v_pages, layer, page_table, start,
+                            n_valid, *, sm_scale: Optional[float] = None):
+    """Chunked prefill attention of one layer for one request through its
+    page-table row, the chunk's K/V already in the slab ``[L, P, page,
+    K * hd]``. See ref.paged_prefill_attention for semantics. The Pallas
+    path (``paged_prefill_attention``) reads only the blocks that hold a
+    position below ``start + n_valid``. Pages that are not whole 8-row
+    tiles, which its copies cannot slice out of the slab, and heads that
+    do not tile 128 lanes (``prefill_lane_groups``) take the oracle's
+    dense window."""
+    D = q.shape[-1]
+    if (pallas_mode() == "tpu" and k_pages.shape[2] % 8 == 0
+            and prefill_lane_groups(k_pages.shape[3] // D, D) is not None):
+        return paged_prefill_kernel(q, k_pages, v_pages, layer, page_table,
+                                    start, n_valid, sm_scale=sm_scale)
+    return ref.paged_prefill_attention(q, k_pages, v_pages, layer,
+                                       page_table, start, n_valid,
+                                       sm_scale=sm_scale)
+
+
+def latent_prefill_attention(q, kv_pages, layer, page_table, start, n_valid,
+                             *, sm_scale: float, v_dim: int):
+    """Chunked latent (MLA) prefill attention of one layer for one
+    request, the chunk's rows already in the latent slab. See
+    ref.latent_prefill_attention for semantics. The Pallas path
+    (``latent_prefill_attention``) reads only the live blocks."""
+    if pallas_mode() == "tpu":
+        return latent_prefill_kernel(q, kv_pages, layer, page_table, start,
+                                     n_valid, sm_scale=sm_scale, v_dim=v_dim)
+    return ref.latent_prefill_attention(q, kv_pages, layer, page_table,
+                                        start, n_valid, sm_scale=sm_scale,
+                                        v_dim=v_dim)
 
 
 def rwkv6_scan(r, k, v, w, u, state=None):

@@ -222,6 +222,70 @@ def latent_decode_attention(q: jax.Array, kv_pages: jax.Array, layer,
     return o.astype(q.dtype)
 
 
+def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, layer, page_table: jax.Array,
+                            start, n_valid, *,
+                            sm_scale: Optional[float] = None) -> jax.Array:
+    """Chunked prefill attention of one layer for ONE request through its
+    page-table row (the oracle; also the path off a TPU, and on one for
+    pages that are not whole 8-row tiles or heads that do not tile 128
+    lanes).
+
+    q: [C, H, D], the chunk's queries at positions ``start ..``; k_pages,
+    v_pages: [L, P, page_size, K * D], the whole slab, the chunk's K/V
+    already written; page_table: [M], the request's row; start: positions
+    cached before the chunk; n_valid: its real rows (unused here: a row
+    past it is padding, whose output nobody reads). The request's whole
+    window of ``M * page_size`` positions is gathered and attended
+    densely under the shifted causal mask ``col <= start + row``, f32
+    softmax: ``attention``'s semantics continued from a cache. Returns
+    [C, H, D] in q.dtype.
+    """
+    del n_valid
+    C, H, D = q.shape
+    page_size = k_pages.shape[2]
+    M = page_table.shape[0]
+    K = k_pages.shape[3] // D
+    G = H // K
+    kc = k_pages[layer, page_table].reshape(M * page_size, K, D)
+    vc = v_pages[layer, page_table].reshape(M * page_size, K, D)
+    scale = sm_scale if sm_scale is not None else D ** -0.5
+    qf = q.astype(jnp.float32).reshape(C, K, G, D) * scale
+    logits = jnp.einsum("qkgd,skd->kgqs", qf, kc.astype(jnp.float32))
+    cols = jnp.arange(M * page_size, dtype=jnp.int32)[None, :]
+    causal = cols <= (start + jnp.arange(C, dtype=jnp.int32))[:, None]
+    logits = jnp.where(causal[None, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    o = jnp.einsum("kgqs,skd->qkgd", probs, vc.astype(jnp.float32))
+    return o.reshape(C, H, D).astype(q.dtype)
+
+
+def latent_prefill_attention(q: jax.Array, kv_pages: jax.Array, layer,
+                             page_table: jax.Array, start, n_valid, *,
+                             sm_scale: float, v_dim: int) -> jax.Array:
+    """Chunked latent (MLA) prefill attention of one layer for ONE request
+    (the oracle): q [C, H, C_lanes], the absorbed queries; kv_pages [L, P,
+    page_size, C_lanes], the latent slab, the chunk's rows already
+    written; the rest as in :func:`paged_prefill_attention`. The window's
+    rows are every head's keys (all lanes) and values (the first
+    ``v_dim``), attended in f32 under ``col <= start + row``. Returns
+    [C, H, v_dim] in q.dtype.
+    """
+    del n_valid
+    C = q.shape[0]
+    page_size = kv_pages.shape[2]
+    M = page_table.shape[0]
+    rows = kv_pages[layer, page_table].reshape(M * page_size, -1)
+    rf = rows.astype(jnp.float32)
+    causal = (jnp.arange(M * page_size, dtype=jnp.int32)[None, :]
+              <= (start + jnp.arange(C, dtype=jnp.int32))[:, None])
+    s = jnp.einsum("qhc,tc->hqt", q.astype(jnp.float32), rf) * sm_scale
+    s = jnp.where(causal[None], s, NEG_INF)
+    probs = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("hqt,tr->qhr", probs, rf[..., :v_dim])
+    return o.astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Chunked time scan (bounded-memory BPTT for the recurrences)
 # ---------------------------------------------------------------------------

@@ -248,18 +248,15 @@ def apply_attention_prefill_paged(p: Params, cfg: ModelConfig, x: jax.Array,
     tail past it is padding: its K/V writes are redirected to the null
     page and no valid query row can attend that far right).
 
-    The chunk's K/V are scattered into the pages FIRST, then the
-    request's whole window of this layer is gathered back ([M * page]
-    positions) and attended causally with the shifted mask ``col <=
-    start + row`` — exactly ``ops.attention``'s semantics continued from
-    a cache, f32 softmax and all, so chunked prefill matches one-shot
-    prefill.
+    The chunk's K/V are scattered into the pages FIRST, then
+    ``ops.paged_prefill_attention`` attends the request's positions
+    causally with the shifted mask ``col <= start + row`` — exactly
+    ``ops.attention``'s semantics continued from a cache, f32 softmax and
+    all, so chunked prefill matches one-shot prefill. On a TPU it reads
+    only the blocks below ``start + n_valid``.
     """
     _, C, _ = x.shape
     page = k_pages.shape[2]
-    M = page_table.shape[0]
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    G = H // K
     tpos = start + jnp.arange(C, dtype=jnp.int32)              # [C]
     q, k, v = _project_qkv(p, cfg, x, tpos[None], use_rope=True)
     valid = jnp.arange(C) < n_valid
@@ -269,18 +266,9 @@ def apply_attention_prefill_paged(p: Params, cfg: ModelConfig, x: jax.Array,
         k[0].reshape(C, -1).astype(k_pages.dtype))
     v_pages = v_pages.at[layer, pid, off].set(
         v[0].reshape(C, -1).astype(v_pages.dtype))
-    kc = k_pages[layer, page_table].reshape(M * page, K, hd)
-    vc = v_pages[layer, page_table].reshape(M * page, K, hd)
-    scale = hd ** -0.5
-    qf = q.astype(jnp.float32).reshape(C, K, G, hd) * scale
-    logits = jnp.einsum("qkgd,skd->kgqs", qf, kc.astype(jnp.float32))
-    cols = jnp.arange(M * page, dtype=jnp.int32)[None, :]      # [1, S]
-    causal = cols <= (start + jnp.arange(C, dtype=jnp.int32))[:, None]
-    logits = jnp.where(causal[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("kgqs,skd->qkgd", probs, vc.astype(jnp.float32))
-    o = o.reshape(1, C, H * hd).astype(x.dtype)
-    out = jnp.einsum("bsf,fd->bsd", o, p["wo"])
+    o = ops.paged_prefill_attention(q[0], k_pages, v_pages, layer,
+                                    page_table, start, n_valid)
+    out = jnp.einsum("bsf,fd->bsd", o.reshape(1, C, -1), p["wo"])
     return out, k_pages, v_pages
 
 
@@ -437,21 +425,21 @@ def apply_mla_prefill_paged(p: Params, cfg: ModelConfig, x: jax.Array,
     """Chunked latent prefill of layer ``layer`` for ONE request, as
     :func:`apply_attention_prefill_paged` does it: the chunk's rows are
     scattered into the slab first (padding rows to the null page), then
-    its absorbed queries attend the request's whole window ``[M * page]``
-    under ``col <= start + row``, f32 softmax."""
+    ``ops.latent_prefill_attention`` attends the request's positions
+    with its absorbed queries under ``col <= start + row``, f32
+    softmax."""
     _, C, _ = x.shape
     page, lanes = kv_pages.shape[2:]
-    M = page_table.shape[0]
     tpos = start + jnp.arange(C, dtype=jnp.int32)
     q, rows = mla_project(p, cfg, x, tpos[None])
     q, rows = _pad_lanes(q, lanes), _pad_lanes(rows, lanes)
     pid = jnp.where(jnp.arange(C) < n_valid, page_table[tpos // page], 0)
     kv_pages = kv_pages.at[layer, pid, tpos % page].set(
         rows[0].astype(kv_pages.dtype))
-    window = kv_pages[layer, page_table].reshape(1, M * page, -1)
-    causal = jnp.arange(M * page, dtype=jnp.int32)[None, :] <= tpos[:, None]
-    o = latent_attention(q, window, causal, mla_scale(cfg), cfg.kv_lora_rank)
-    return mla_out(p, cfg, o), kv_pages
+    o = ops.latent_prefill_attention(q[0], kv_pages, layer, page_table,
+                                     start, n_valid, sm_scale=mla_scale(cfg),
+                                     v_dim=cfg.kv_lora_rank)
+    return mla_out(p, cfg, o[None]), kv_pages
 
 
 def init_cross_attention(key, cfg: ModelConfig) -> Params:

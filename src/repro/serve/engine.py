@@ -84,8 +84,10 @@ core, jax 0.9.0), about fifteen microseconds a working tick.
 - ``serve.admit``: the admission loop;
 - ``serve.kv.poll``: one pop from the request queue, with the slot
   token handed back;
-- ``serve.prefill`` (``rid``, ``start``, ``tokens``): one prompt chunk,
-  from building it to the jitted call's return;
+- ``serve.prefill`` (``rid``, ``start``, ``tokens``, ``attn_blocks``:
+  the blocks of ``Model.decode_block_rows`` positions that the chunk's
+  attention reads in each layer, ``ceil((start + tokens) / block)``):
+  one prompt chunk, from building it to the jitted call's return;
 - ``serve.prefill.wait`` (``rid``): the wait for a prompt's first token;
 - ``serve.decode`` (``tokens``: live slots, ``kv_positions``: the sum of
   ``attention_lengths()``, ``attn_blocks``: the blocks of
@@ -351,6 +353,9 @@ class ContinuousEngine:
     divides 512) raises ValueError here, not at the first compile.
     For a model with routed experts ``metrics["held_expert_tokens"]``
     counts the decode steps' (token, held expert) assignments.
+    ``metrics["prefill_attn_blocks"]`` sums over prompt chunks the blocks
+    of ``Model.decode_block_rows`` positions their attention reads in
+    each layer, ``ceil((start + tokens) / block)``.
     """
 
     def __init__(self, model: Model, params, *, max_slots: int = 4,
@@ -391,7 +396,7 @@ class ContinuousEngine:
         self.results: Dict[str, Dict[str, Any]] = {}  # local submissions
         self.metrics = {"admitted": 0, "completed": 0, "preempted": 0,
                         "rejected": 0, "decode_steps": 0,
-                        "prefill_chunks": 0}
+                        "prefill_chunks": 0, "prefill_attn_blocks": 0}
         self._attn_block = model.decode_block_rows(page_size)
         self._counts_held = counts_held_experts(model)
         if self._counts_held:
@@ -603,8 +608,10 @@ class ContinuousEngine:
         n_valid = min(C, len(prompt) - slot.prompt_pos)
         if not self._ensure_capacity(idx, slot.prompt_pos + n_valid - 1):
             return  # wait for pages (or we were the preemption victim)
+        blocks = -(-(slot.prompt_pos + n_valid) // self._attn_block)
         with TraceAnnotation(SPAN_PREFILL, rid=slot.req.id,
-                             start=slot.prompt_pos, tokens=n_valid):
+                             start=slot.prompt_pos, tokens=n_valid,
+                             attn_blocks=blocks):
             chunk = np.zeros((1, C), np.int32)
             chunk[0, :n_valid] = prompt[slot.prompt_pos:
                                         slot.prompt_pos + n_valid]
@@ -613,6 +620,7 @@ class ContinuousEngine:
                 jnp.asarray(self._tables[idx]), jnp.int32(slot.prompt_pos),
                 jnp.int32(n_valid))
         self.metrics["prefill_chunks"] += 1
+        self.metrics["prefill_attn_blocks"] += blocks
         slot.prompt_pos += n_valid
         slot.length = slot.prompt_pos
         self._lengths[idx] = slot.length

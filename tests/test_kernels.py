@@ -11,7 +11,10 @@ from hypothesis import HealthCheck, given, settings, strategies as st
 from repro.kernels import ref
 from repro.kernels.decode_attention import (flash_decode, flash_decode_paged,
                                             latent_decode_paged,
-                                            paged_block_rows)
+                                            latent_prefill_attention,
+                                            paged_block_rows,
+                                            paged_prefill_attention,
+                                            prefill_lane_groups)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba2_scan import mamba2_chunked
 from repro.kernels.rwkv6_scan import rwkv6_chunked
@@ -417,6 +420,91 @@ class TestMamba2:
             return (y ** 2).sum()
         g = jax.grad(loss)(x)
         assert bool(jnp.isfinite(g).all())
+
+
+class TestPagedPrefill:
+    """The paged prefill kernels (interpret mode) against their oracles,
+    the dense window: one request's chunk of ``C`` rows at ``start``,
+    its pages scattered over a slab of two layers read at layer 1, every
+    page past the live length holding NaN. Starts at 0, inside a block,
+    a chunk across two of the walk's blocks of 512 positions, a ragged
+    last chunk, and a length at an exact block multiple; only the chunk's
+    real rows are compared (a padding row's output is unread)."""
+
+    C = 32
+    #: (start, n_valid): 0, mid-block, across a block, ragged, exact
+    STARTS = [(0, 32), (200, 32), (500, 32), (700, 13), (992, 32)]
+
+    @staticmethod
+    def _slab(lanes, n_src, start, n_valid, seed, M=72, page=16):
+        """``(slabs, poisoned slabs, table)``: ``n_src`` slabs of two
+        layers, pages past the live length NaN in the poisoned copy."""
+        rng = np.random.default_rng(seed)
+        P = M + 3
+        table = rng.permutation(np.arange(1, P))[:M].astype(np.int32)
+        slabs = [rng.standard_normal((2, P, page, lanes)).astype(np.float32)
+                 for _ in range(n_src)]
+        live = set(table[:-(-(start + n_valid) // page)].tolist())
+        dead = np.array([p not in live for p in range(P)])
+        poisoned = [x.copy() for x in slabs]
+        for x in poisoned:
+            x[:, dead] = np.nan
+        return slabs, poisoned, table
+
+    @pytest.mark.parametrize("start,n_valid", STARTS)
+    @pytest.mark.parametrize("H,K,D", [
+        (8, 4, 64),       # GQA, two kv heads a 128-lane tile
+        (4, 4, 128),      # MHA, a head a tile
+        (8, 2, 128),      # GQA, four query heads a kv head
+    ])
+    def test_gqa_matches_oracle(self, H, K, D, start, n_valid):
+        slabs, poisoned, table = self._slab(K * D, 2, start, n_valid,
+                                            seed=start + H + D)
+        q = np.random.default_rng(1).standard_normal(
+            (self.C, H, D)).astype(np.float32)
+        got = np.asarray(paged_prefill_attention(
+            jnp.asarray(q), *map(jnp.asarray, poisoned), 1,
+            jnp.asarray(table), start, n_valid, interpret=True))
+        want = np.asarray(ref.paged_prefill_attention(
+            jnp.asarray(q), *map(jnp.asarray, slabs), 1, jnp.asarray(table),
+            start, n_valid))
+        assert got.shape == (self.C, H, D) and np.isfinite(got).all()
+        np.testing.assert_allclose(got[:n_valid], want[:n_valid],
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("start,n_valid", STARTS)
+    @pytest.mark.parametrize("H", [4, 16])
+    def test_latent_matches_oracle(self, H, start, n_valid):
+        """Moonlight's latent rows: 576 lanes (512 latent, 64 rope) in
+        640, values the first 512."""
+        slabs, poisoned, table = self._slab(640, 1, start, n_valid,
+                                            seed=start + H)
+        for x in slabs + poisoned:
+            x[..., 576:] = 0.0
+        q = np.zeros((self.C, H, 640), np.float32)
+        q[..., :576] = np.random.default_rng(2).standard_normal(
+            (self.C, H, 576))
+        kw = dict(sm_scale=192 ** -0.5, v_dim=512)
+        got = np.asarray(latent_prefill_attention(
+            jnp.asarray(q), jnp.asarray(poisoned[0]), 1, jnp.asarray(table),
+            start, n_valid, interpret=True, **kw))
+        want = np.asarray(ref.latent_prefill_attention(
+            jnp.asarray(q), jnp.asarray(slabs[0]), 1, jnp.asarray(table),
+            start, n_valid, **kw))
+        assert got.shape == (self.C, H, 512) and np.isfinite(got).all()
+        np.testing.assert_allclose(got[:n_valid], want[:n_valid],
+                                   atol=2e-5 * np.abs(want).max(),
+                                   rtol=2e-5)
+
+    @pytest.mark.parametrize("K,D,groups", [
+        (16, 64, 2), (4, 128, 1), (2, 16, 2), (4, 16, 4), (8, 112, None),
+        (20, 80, None),
+    ])
+    def test_lane_groups_follow_the_shape(self, K, D, groups):
+        """Kv heads a lane group holds: one 128-lane tile's, one head of
+        whole tiles, every head of a slab under a tile; none where the
+        heads do not tile the lanes (those take the dense window)."""
+        assert prefill_lane_groups(K, D) == groups
 
 
 class TestLatentDecode:

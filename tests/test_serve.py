@@ -170,6 +170,52 @@ class TestContinuousEngine:
             row = _static_row(m, params, toks, mn)
             assert eng.results[rid]["tokens"] == list(row)
 
+    def test_prefill_attn_blocks_counts_the_walked_blocks(self, model_params):
+        """``metrics["prefill_attn_blocks"]`` sums, over the prompt chunks,
+        ``ceil((start + tokens) / T)`` with ``T`` the model's block of the
+        walk (a page of 8 here): prompts of 5, 13 and 21 in chunks of 4
+        end at 4, 5 | 4, 8, 12, 13 | 4, 8, 12, 16, 20, 21."""
+        m, params = model_params
+        eng = ContinuousEngine(m, params, max_slots=2, page_size=8,
+                               max_len=64, prefill_chunk=4, eos_id=None)
+        assert m.decode_block_rows(8) == 8
+        for n in (5, 13, 21):
+            eng.submit(list(range(2, 2 + n)), 2)
+        eng.run_until_idle()
+        assert eng.metrics["prefill_chunks"] == 2 + 4 + 6
+        assert eng.metrics["prefill_attn_blocks"] == (1 + 1) + (
+            1 + 1 + 2 + 2) + (1 + 1 + 2 + 2 + 3 + 3)
+
+    def test_prefill_kernel_serves_the_dense_paths_tokens(self, model_params,
+                                                          monkeypatch):
+        """Served with the paged kernels (interpret mode, as on a TPU),
+        the tokens are the dense path's, prompts across several of the
+        walk's blocks included."""
+        import functools
+
+        from repro.kernels import decode_attention as da
+        from repro.kernels import ops
+
+        m, params = model_params
+        rng = np.random.default_rng(5)
+        reqs = [(rng.integers(2, SMOKE.vocab_size, n).tolist(), 4)
+                for n in (5, 13, 21, 30)]
+
+        def serve():
+            eng = ContinuousEngine(m, params, max_slots=2, page_size=8,
+                                   max_len=64, prefill_chunk=4, eos_id=None)
+            rids = [eng.submit(t, n) for t, n in reqs]
+            eng.run_until_idle()
+            return [eng.results[r]["tokens"] for r in rids]
+
+        dense = serve()
+        monkeypatch.setattr(ops, "pallas_mode", lambda: "tpu")
+        monkeypatch.setattr(ops, "paged_prefill_kernel", functools.partial(
+            da.paged_prefill_attention, interpret=True))
+        monkeypatch.setattr(ops, "flash_decode_paged", functools.partial(
+            da.flash_decode_paged, interpret=True))
+        assert serve() == dense
+
     def test_eviction_returns_pages(self, model_params):
         m, params = model_params
         eng = ContinuousEngine(m, params, max_slots=2, page_size=8,

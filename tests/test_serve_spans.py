@@ -192,6 +192,18 @@ def test_prefill_spans_cover_each_prompt_in_chunks(served):
     assert waits == set(by_rid)
 
 
+def test_prefill_span_counts_the_kernels_blocks(served):
+    """A ``serve.prefill`` span's ``attn_blocks`` is the number of blocks
+    of the walk (``paged_block_rows`` positions each) up to the chunk's
+    end, the same key the ``serve.decode`` span carries."""
+    T = paged_block_rows(8, SMOKE.num_kv_heads * SMOKE.hd)
+    got = [(e["stats"]["attn_blocks"],
+            -(-(e["stats"]["start"] + e["stats"]["tokens"]) // T))
+           for e in _named(served, "serve.prefill")]
+    assert got and all(a == b for a, b in got)
+    assert max(a for a, _ in got) > 1
+
+
 def test_every_store_command_is_inside_one_kv_span(served):
     cmds = [e for e in served["events"] if e["name"] == "test.cmd"]
     assert {c["stats"]["cmd"] for c in cmds} >= {

@@ -3,18 +3,21 @@ TPU v5e.
 
 Interpret mode (tests/test_kernels.py) checks what the kernels compute,
 not whether the TPU compiler accepts their tiling. These tests compile
-``flash_decode_paged``, ``flash_decode`` and the ``flash_attention``
-forward at qwen1.5-0.5b's serving widths (16 query and 16 kv heads, head
-dim 64, bfloat16; 8 slots of 2048 positions) for one chip of a described
-``v5e:2x2`` topology, with no chip attached. They also compile
+``flash_decode_paged``, ``paged_prefill_attention``, ``flash_decode``
+and the ``flash_attention`` forward at qwen1.5-0.5b's serving widths
+(16 query and 16 kv heads, head dim 64, bfloat16; 8 slots of 2048
+positions) for one chip of a described ``v5e:2x2`` topology, with no
+chip attached. They also compile
 ``ContinuousEngine``'s decode and prefill steps for qwen1.5-0.5b at
 those sizes and check that the page slab stays whole: no temporaries
 and no data move as large as one layer of it. The same holds for
-moonlight-16b-a3b's latent kernel ``latent_decode_paged`` and its two
-steps, at the benchmark cell's sizes (24 slots of 7,168 positions, 8 of
-64 experts held), whose decode step hands the latent kernel the same
-scalar operands as ``flash_decode_paged``. Nothing runs, so nothing
-about results or speed is checked here.
+moonlight-16b-a3b's latent kernels ``latent_decode_paged`` and
+``latent_prefill_attention`` and its two steps, at the benchmark cell's
+sizes (24 slots of 7,168 positions, 8 of 64 experts held), whose decode
+step hands the latent kernel the same scalar operands as
+``flash_decode_paged``. Both cells' prefill steps run one prefill kernel
+a layer and hold no f32 tensor as wide as the request's window. Nothing
+runs, so nothing about results or speed is checked here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -36,7 +39,9 @@ from repro.configs.qwen1_5_0_5b import CONFIG as QWEN
 from repro.kernels import ops
 from repro.kernels.decode_attention import (MAX_BLOCK_ELEMS, check_page_size,
                                             flash_decode, flash_decode_paged,
-                                            latent_decode_paged)
+                                            latent_decode_paged,
+                                            latent_prefill_attention,
+                                            paged_prefill_attention)
 from repro.kernels.flash_attention import flash_attention
 from repro.models.model import build_model
 from repro.serve.engine import make_decode_step, make_prefill_step
@@ -185,11 +190,55 @@ def test_engine_step_keeps_the_slab_whole(one_chip, monkeypatch, step):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2 * layer, f"{temp} B of temporaries"
     text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == (step == "decode")
-    kernel = re.search(r"^\s*%flash_decode_paged\S* = .* custom-call\(", text,
-                       re.M)
-    assert (kernel is not None) == (step == "decode")
+    name = "flash_decode_paged" if step == "decode" else \
+        "paged_prefill_attention"
+    assert re.search(rf"^\s*%{name}\S* = .* custom-call\(", text, re.M)
     assert _large_moves(text, layer) == []
+
+
+def _prefill_shapes(heads, lanes, M, kv_heads=None, hd=None):
+    q = ((CHUNK, heads, hd or lanes), jnp.bfloat16)
+    slab = ((2, M + 1, PAGE, lanes), jnp.bfloat16)
+    rest = (((), jnp.int32), ((M,), jnp.int32), ((), jnp.int32),
+            ((), jnp.int32))
+    return (q, slab, slab) + rest if kv_heads else (q, slab) + rest
+
+
+@pytest.mark.parametrize("kernel", ["gqa", "latent"])
+def test_prefill_kernels_compile(one_chip, kernel):
+    """The cells' prefill attention: qwen1.5-0.5b's 16 heads of 64 over a
+    2,048-position window, Moonlight's 16 heads over latent rows of 640
+    lanes and a 7,168-position window, a chunk of 128 rows."""
+    if kernel == "gqa":
+        _compile(paged_prefill_attention, one_chip,
+                 *_prefill_shapes(H, K * HD, MAX_LEN // PAGE, K, HD))
+    else:
+        _compile(lambda q, s, layer, t, st, n: latent_prefill_attention(
+            q, s, layer, t, st, n, sm_scale=192 ** -0.5, v_dim=512),
+            one_chip, *_prefill_shapes(16, 640, ML_MAX_LEN // PAGE))
+
+
+@pytest.mark.parametrize("cell", ["qwen", "moonlight"])
+def test_prefill_step_attends_the_live_blocks(one_chip, monkeypatch, cell):
+    """Each cell's prefill step at its sizes runs one prefill kernel a
+    layer (one call in each scan over stacked layers: qwen's 24, and
+    Moonlight's dense layer and 26 MoE layers) and holds no f32 tensor
+    whose last dim is the request's window (``M * page`` positions), as
+    the dense window's scores and weights were."""
+    monkeypatch.setattr(ops, "pallas_mode", lambda: "tpu")
+    if cell == "qwen":
+        model, slots, max_len = build_model(QWEN), SLOTS, MAX_LEN
+        name, scans = "paged_prefill_attention", 1
+    else:
+        model = build_model(MOONLIGHT.replace(experts_held=8))
+        slots, max_len = ML_SLOTS, ML_MAX_LEN
+        name, scans = "latent_prefill_attention", 2
+    compiled, _ = _engine_step(model, one_chip, "prefill", slots, max_len)
+    text = compiled.as_text()
+    calls = re.findall(rf"^\s*%{name}\S* = .* custom-call\(", text, re.M)
+    assert len(calls) == scans == text.count("tpu_custom_call")
+    wide = re.findall(rf"f32\[[\d,]*,{max_len}\]", text)
+    assert wide == []
 
 
 def test_flash_decode_compiles(one_chip):
